@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import time
 
+import jax
 import numpy as np
 
 from .common import (BENCH_DATASETS, BENCH_MAX_SIZE, SMOKE, bench_iters,
@@ -170,13 +171,16 @@ def _bench_planner(rows):
 def _bench_expansion_plane(rows):
     """One batched mining level under each expansion plane (PR 2 tentpole).
 
-    `xla` is the production CPU path; `pallas_interp` runs the fused kernel
-    in interpret mode (this container has no TPU), so its time is the
-    interpreter's, not the hardware's — the row exists to pin *bit-exact
-    parity* (parity=1.0) and to give TPU runs a ready-made A/B harness
-    (set pallas_interpret=False there).
+    `xla` is the production path; `pallas_interp` runs the fused kernel in
+    interpret mode, so its time is the interpreter's, not the hardware's —
+    the row exists to pin *bit-exact parity* (parity=1.0).  The kernel does
+    not lower for a TPU (`frontier_expand.ops.interpret_mode`), so a TPU run
+    has no such rows.
     """
     import dataclasses
+
+    if jax.default_backend() == "tpu":
+        return
 
     from repro.core import MatchConfig
     from repro.core.batched import evaluate_level_batched

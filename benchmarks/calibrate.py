@@ -151,6 +151,7 @@ def fit_cost_model(iters: int = 20) -> dict:
         "escalation_fraction": None,
         "vmap_factor": float(round(vmap_factor, 3)),
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "source": "benchmarks/calibrate.py",
         "probe": {
             "n": n, "k": k,

@@ -153,6 +153,9 @@ def main(argv=None) -> int:
                          "and write planner_calibration.json; --smoke "
                          "always runs it")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.smoke:
         # must be set before benchmark modules import benchmarks.common

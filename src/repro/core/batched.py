@@ -56,7 +56,8 @@ import numpy as np
 from .graph import DataGraph, DeviceGraph
 from .pattern import Pattern
 from .plan import PatternPlan, make_plan, stack_plans
-from .matcher import MatchConfig, match_block, transient_match_bytes
+from .matcher import (MatchConfig, batch_checks, match_block,
+                      transient_match_bytes)
 from . import mis as mis_lib
 from . import metrics as metrics_lib
 
@@ -127,9 +128,9 @@ def _step_fn(metric: str, k: int, cfg: MatchConfig, unbatched: bool = False,
 
     if metric in ("mis", "mis_luby"):
 
-        def step_one(g, plan, block_start, bm, cnt, tau):
+        def step_one(g, plan, block_start, bm, cnt, tau, checks=None):
             emb, n_valid, found, ovf, peak = match_block(
-                g, plan, block_start, cfg)
+                g, plan, block_start, cfg, checks)
             if metric == "mis":
                 bm, cnt = mis_lib.mis_greedy_update(
                     bm, cnt, emb, n_valid, tau, k)
@@ -149,18 +150,19 @@ def _step_fn(metric: str, k: int, cfg: MatchConfig, unbatched: bool = False,
                 bm, cnt = out[0], out[1]
                 rest = tuple(x[None] for x in out[2:])
                 return ((bm[None], cnt[None]), cnt[None]) + rest
+            checks = batch_checks(plans)
             out = jax.vmap(
                 lambda plan, bm, cnt, tau: step_one(
-                    g, plan, block_start, bm, cnt, tau))(
+                    g, plan, block_start, bm, cnt, tau, checks))(
                 plans, bitmaps, counts, taus)
             bitmaps, counts = out[0], out[1]
             return ((bitmaps, counts), counts) + tuple(out[2:])
 
     elif metric in ("mni", "frac"):
 
-        def step_one(g, plan, block_start, table):
+        def step_one(g, plan, block_start, table, checks=None):
             emb, n_valid, found, ovf, peak = match_block(
-                g, plan, block_start, cfg)
+                g, plan, block_start, cfg, checks)
             if metric == "mni":
                 table = metrics_lib.mni_update(table, emb, n_valid, k)
                 value = metrics_lib.mni_value(table)
@@ -177,8 +179,10 @@ def _step_fn(metric: str, k: int, cfg: MatchConfig, unbatched: bool = False,
                 squeeze = jax.tree_util.tree_map(lambda a: a[0], plans)
                 out = step_one(g, squeeze, block_start, state[0])
                 return tuple(x[None] for x in out)
+            checks = batch_checks(plans)
             out = jax.vmap(
-                lambda plan, table: step_one(g, plan, block_start, table))(
+                lambda plan, table: step_one(g, plan, block_start, table,
+                                             checks))(
                 plans, state)
             return out
 

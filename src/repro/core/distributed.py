@@ -42,14 +42,13 @@ from typing import Any, Iterator, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-
-from repro import jax_compat
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .graph import DataGraph, DeviceGraph
 from .pattern import Pattern
 from .plan import PatternPlan, make_plan, stack_plans
-from .matcher import MatchConfig, match_block, transient_match_bytes
+from .matcher import (MatchConfig, batch_checks, match_block,
+                      transient_match_bytes)
 from . import mis as mis_lib
 from . import batched as batched_lib
 
@@ -62,7 +61,15 @@ __all__ = ["mining_mesh", "sharded_mis_step", "distributed_support",
 def mining_mesh(axis: str = "workers", devices=None) -> Mesh:
     """A 1-D mesh over all available devices (mining shards roots, period)."""
     devices = np.array(jax.devices() if devices is None else devices)
-    return jax_compat.make_mesh((devices.size,), (axis,), devices=devices)
+    return jax.make_mesh((devices.size,), (axis,),
+                         axis_types=(AxisType.Auto,), devices=devices)
+
+
+def _replicated(tree, mesh: Mesh):
+    """``tree`` placed whole on every device of ``mesh``, once — the graph
+    and the plans every step reads, instead of one device's copy moved to
+    the mesh at each dispatch."""
+    return jax.device_put(tree, NamedSharding(mesh, P()))
 
 
 def _luby_rounds_global(bitmap, count, emb, n_valid, tau, k: int, n: int,
@@ -71,7 +78,7 @@ def _luby_rounds_global(bitmap, count, emb, n_valid, tau, k: int, n: int,
 
     bitmap/count are replicated; emb/n_valid are per-device locals.
     """
-    ndev = jax_compat.axis_size(axis)
+    ndev = jax.lax.axis_size(axis)
     didx = jax.lax.axis_index(axis).astype(jnp.int32)
     rowid = jnp.arange(cap, dtype=jnp.int32)
     gprio_base = didx * cap
@@ -134,7 +141,7 @@ def sharded_mis_step(g: DeviceGraph, plan: PatternPlan, block_starts,
                                       cfg.cap, axis)
         return bm, cnt, jax.lax.psum(found, axis)
 
-    return jax_compat.shard_map(
+    return jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P(axis), P(), P()),
@@ -165,10 +172,12 @@ def sharded_batched_mis_step(g: DeviceGraph, plans: PatternPlan, block_starts,
     each (P,).
     """
 
+    checks = batch_checks(plans)
+
     def step(block_start, bms, cnts):
         def one(plan, bm, cnt, tau):
             emb, n_valid, found, ovf, peak = match_block(
-                g, plan, block_start[0], cfg)
+                g, plan, block_start[0], cfg, checks)
             bm, cnt = _luby_rounds_global(bm, cnt, emb, n_valid, tau, k, n,
                                           cfg.cap, axis)
             return bm, cnt, found, ovf, peak
@@ -178,7 +187,7 @@ def sharded_batched_mis_step(g: DeviceGraph, plans: PatternPlan, block_starts,
                 jax.lax.psum(ovf.astype(jnp.int32), axis) > 0,
                 jax.lax.pmax(peak, axis))
 
-    return jax_compat.shard_map(
+    return jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P(axis), P(), P()),
@@ -270,8 +279,9 @@ def iter_batched_supports(
     mesh = mesh or mining_mesh(axis)
     ndev = int(np.prod(list(mesh.shape.values())))
     cfg = match_cfg or MatchConfig.for_graph(host_g)
-    dev_g = DeviceGraph.from_host(host_g)
-    plans = stack_plans([make_plan(p, host_g) for p in patterns])
+    dev_g = _replicated(DeviceGraph.from_host(host_g), mesh)
+    plans = _replicated(
+        stack_plans([make_plan(p, host_g) for p in patterns]), mesh)
     n = host_g.n
     P_ = len(patterns)
     taus_np = np.asarray(taus, np.int64)
@@ -505,8 +515,8 @@ def distributed_support(
     mesh = mesh or mining_mesh(axis)
     ndev = int(np.prod(list(mesh.shape.values())))
     cfg = match_cfg or MatchConfig.for_graph(host_g)
-    dev_g = DeviceGraph.from_host(host_g)
-    plan = make_plan(pat, host_g)
+    dev_g = _replicated(DeviceGraph.from_host(host_g), mesh)
+    plan = _replicated(make_plan(pat, host_g), mesh)
     n = host_g.n
     bitmap = mis_lib.bitmap_init(n)
     count = jnp.int32(0)

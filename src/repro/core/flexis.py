@@ -169,6 +169,9 @@ class MiningResult:
     # session) — results are bit-identical with or without them; see
     # `core/health.py`.  Excluded from resume bit-identity comparisons.
     health: RunHealth = dataclasses.field(default_factory=RunHealth)
+    # "platform:device_kind" of the device that held the data graph, where
+    # the single-device planes ran every step
+    device: str = ""
 
 
 @dataclasses.dataclass
@@ -399,7 +402,8 @@ def mine(g: DataGraph, cfg: MiningConfig, *, hooks=None,
     # planner's static root-block schedule (cfg.root_order)
     import jax
 
-    cost = planner_lib.load_calibration()
+    device = next(iter(dev_g.labels.devices()))
+    cost = planner_lib.load_calibration(device=device)
     n_devices = jax.local_device_count()
     if hooks is not None and hasattr(hooks, "pin_calibration"):
         # sessions pin the planner inputs in the snapshot so a resume on a
@@ -411,7 +415,7 @@ def mine(g: DataGraph, cfg: MiningConfig, *, hooks=None,
         cost = CostModel.from_dict(pinned)
         n_devices = int(pinned.get("n_devices", n_devices))
     planner = ExecutionPlanner(g, cfg, cost_model=cost,
-                               n_devices=n_devices)
+                               n_devices=n_devices, platform=device.platform)
     block_order = planner.block_order
     deadline = (None if cfg.time_limit_s is None
                 else t0 + max(cfg.time_limit_s - elapsed0, 0.0))
@@ -707,4 +711,5 @@ def mine(g: DataGraph, cfg: MiningConfig, *, hooks=None,
         timed_out=timed_out,
         peak_device_bytes=peak_bytes,
         health=health,
+        device=f"{device.platform}:{device.device_kind}",
     )

@@ -7,7 +7,8 @@ pattern vertex per level, in lockstep:
   level i:  anchors  = emb[:, anchor_pos[i]]
             cands    = chunked gather of the anchors' CSR adjacency rows
             mask     = label ∧ degree ∧ injectivity ∧ edge-checks
-            emb'     = cumsum-compaction of the masked (cap × chunk) grid
+            emb'     = cumsum-compaction of the masked (rows × chunk) grid,
+                       walked in row tiles over the valid rows only
 
 Edge-existence checks run a fixed-depth branchless binary search over each
 CSR row (no hash tables, no int64 keys — int32 only, TPU-friendly).
@@ -33,8 +34,8 @@ Two expansion planes implement the level step (``MatchConfig.expansion``):
     CSR arrays VMEM-resident across chunks.  Bit-identical to the
     single-phase XLA pipeline (survivor order included); under ``vmap``
     the pattern axis becomes a kernel-grid dimension, so a batched level
-    is still one launch.  See ``docs/kernels.md`` for the interpret-mode
-    fallback rule.
+    is still one launch.  It runs interpreted off-TPU and is refused on a
+    TPU, where Mosaic cannot lower it yet (``docs/kernels.md``).
 """
 from __future__ import annotations
 
@@ -49,8 +50,8 @@ import numpy as np
 from .graph import DataGraph, DeviceGraph
 from .plan import PatternPlan
 
-__all__ = ["MatchConfig", "match_block", "edge_exists", "device_graph_tuple",
-           "transient_match_bytes"]
+__all__ = ["MatchConfig", "match_block", "batch_checks", "edge_exists",
+           "device_graph_tuple", "transient_match_bytes"]
 
 
 # Register the graph/plan dataclasses as pytrees so they pass through jit
@@ -115,11 +116,10 @@ class MatchConfig:
     two_phase: bool = False
     # expansion plane: "xla" = per-chunk op pipeline (reference), "pallas" =
     # fused per-level kernel (repro.kernels.frontier_expand), bit-identical
-    # to the single-phase xla pipeline.
+    # to the single-phase xla pipeline.  Its mode follows the platform
+    # (`frontier_expand.ops.interpret_mode`): building a "pallas" config on
+    # a TPU raises, since the kernel does not lower there yet.
     expansion: str = "xla"
-    # run the Pallas kernel in interpret mode (required off-TPU; this
-    # container is CPU-only).  Ignored when expansion == "xla".
-    pallas_interpret: bool = True
 
     def __post_init__(self):
         if self.expansion not in ("xla", "pallas"):
@@ -131,12 +131,10 @@ class MatchConfig:
         # `overflowed`, but configs should say what they run).
         if self.expansion == "pallas" and self.two_phase:
             object.__setattr__(self, "two_phase", False)
-        # pallas_interpret is a pallas-plane knob; canonicalize it on the
-        # xla plane so configs that run the identical program hash equal
-        # (MatchConfig keys both the match_block jit cache and the batched
-        # step-program cache).
-        if self.expansion == "xla" and not self.pallas_interpret:
-            object.__setattr__(self, "pallas_interpret", True)
+        if self.expansion == "pallas":
+            from repro.kernels.frontier_expand.ops import interpret_mode
+
+            interpret_mode()  # raises on a TPU
 
     @classmethod
     def for_graph(cls, g: DataGraph, *, cap: int = 8192, root_block: int = 4096,
@@ -164,20 +162,23 @@ class MatchConfig:
 def transient_match_bytes(cfg: MatchConfig, k: int) -> int:
     """Transient device footprint of one match step for ONE pattern (bytes).
 
-    Counts the two (cap, k) int32 frontier tables plus the
-    (cap × chunk) candidate-expansion grid with its per-lane intermediates
-    (≈ k + 8 int32 each: candidate rows, mask/cumsum/dest lanes).
+    Counts the two (cap, k) int32 frontier tables plus one
+    candidate-expansion grid with its per-lane intermediates (≈ k + 8
+    int32 each: candidate rows, mask/cumsum/dest lanes): a (row tile ×
+    chunk) tile on the "xla" plane, the whole (cap × chunk) grid on the
+    "pallas" plane.
 
     This is a *per-pattern* number: the batched plane runs P patterns per
     program (leading pattern axis), so its peak transient footprint is
     ``bucket_size(P) · transient_match_bytes(cfg, k)`` — exactly how
     ``core/batched.py`` accounts it, keeping sequential and batched
     ``peak_device_bytes`` telemetry consistent.  On the "pallas" expansion
-    plane the same buffers exist but live in VMEM scratch for the duration
-    of a level instead of spilling to HBM between pipeline stages.
+    plane these buffers live in VMEM scratch for the duration of a level
+    instead of spilling to HBM between pipeline stages.
     """
     emb = cfg.cap * k * 4
-    return emb * 2 + cfg.cap * cfg.chunk * (k + 8) * 4
+    rows = cfg.cap if cfg.expansion == "pallas" else _row_tile(cfg)
+    return emb * 2 + rows * cfg.chunk * (k + 8) * 4
 
 
 def edge_exists(indptr, indices, u, v, n_iters: int):
@@ -239,8 +240,25 @@ def _init_roots(g: DeviceGraph, plan: PatternPlan, block_start, cfg: MatchConfig
     return emb[:cap], count
 
 
+# Lanes of one expansion tile (rows × chunk): small enough that the chunks
+# only a few hub rows reach stay cheap, large enough that a full frontier
+# takes few tiles.
+TILE_LANES = 2048
+
+
+# Scatter index of the lanes that keep nothing: past the end of every
+# target, so the scatter drops them instead of writing them all to one
+# trash row.
+_DROP = np.iinfo(np.int32).max
+
+
+def _row_tile(cfg: MatchConfig) -> int:
+    """Frontier rows per expansion tile (static per geometry)."""
+    return max(1, min(cfg.cap, TILE_LANES // cfg.chunk))
+
+
 def _expand_level(g: DeviceGraph, plan: PatternPlan, emb, count, level: int,
-                  cfg: MatchConfig):
+                  cfg: MatchConfig, checks=None):
     """Extend every partial embedding by pattern-order vertex `level`.
 
     emb: (cap, k) int32 frontier (columns ≥ level are -1); count: () int32
@@ -249,13 +267,28 @@ def _expand_level(g: DeviceGraph, plan: PatternPlan, emb, count, level: int,
     (chunk, row, position) order — the order the greedy-mIS metric consumes.
     Dispatches to the fused Pallas kernel when cfg.expansion == "pallas"
     (bit-identical to the single-phase pipeline below).
+
+    The work follows the frontier, not its capacity: chunk c walks, in
+    tiles of ``_row_tile(cfg)`` rows, only the rows whose degree reaches into
+    it (compacted in row order), and the chunk loop stops at the widest
+    valid row's degree.  Rows past ``count`` and chunks past a row's degree
+    gather nothing, so skipping them changes no result.
+
+    ``checks`` = (out, in) (k, k) bool: the edge checks to compute at all
+    (default: the plan's own).  A check nobody asks for passes every lane,
+    so skipping its bisect changes no result; a vmapped caller passes the
+    OR over its batch, unbatched, and the skip stays a real branch.
     """
     if cfg.expansion == "pallas":
         from repro.kernels.frontier_expand.ops import frontier_expand_level
 
         return frontier_expand_level(g, plan, emb, count, level, cfg)
     cap, C, k = cfg.cap, cfg.chunk, plan.k
+    T = _row_tile(cfg)
     i = level  # python int (static): column being filled
+    # rows that can be valid: the root frontier holds at most one block
+    n_rows = min(cap, cfg.root_block) if i == 1 else cap
+    n_slots = -(-n_rows // T) * T    # active-row list padded to whole tiles
     n_idx = g.out_indices.shape[0]
     # concatenated adjacency so out/in selection is an offset, not two gathers
     indices_cat = jnp.concatenate([g.out_indices, g.in_indices])
@@ -267,91 +300,134 @@ def _expand_level(g: DeviceGraph, plan: PatternPlan, emb, count, level: int,
     out_start = g.out_indptr[anchors_safe].astype(jnp.int32)
     in_start = g.in_indptr[anchors_safe].astype(jnp.int32)
     start = jnp.where(use_out, out_start, in_start + n_idx)
-    deg = jnp.where(
-        use_out,
-        _degrees(g.out_indptr, anchors_safe),
-        _degrees(g.in_indptr, anchors_safe),
-    )
     row_valid = jnp.arange(cap, dtype=jnp.int32) < count
+    deg = jnp.where(
+        row_valid,
+        jnp.where(use_out, _degrees(g.out_indptr, anchors_safe),
+                  _degrees(g.in_indptr, anchors_safe)),
+        0)
+    row_ids = jnp.arange(n_rows, dtype=jnp.int32)
+    # one gather per lane and filter, not two
+    out_deg = jnp.diff(g.out_indptr).astype(jnp.int32)
+    in_deg = jnp.diff(g.in_indptr).astype(jnp.int32)
 
-    out_emb0 = jnp.full((cap + 1, k), -1, dtype=jnp.int32)
-
-    def _cheap_mask(cand, cand_safe, in_deg_range):
-        mask = row_valid[:, None] & in_deg_range
+    def _cheap_mask(emb_t, cand, cand_safe, in_deg_range):
+        mask = in_deg_range
         mask &= g.labels[cand_safe] == plan.cand_label[i]
-        mask &= _degrees(g.out_indptr, cand_safe) >= plan.min_out[i]
-        mask &= _degrees(g.in_indptr, cand_safe) >= plan.min_in[i]
+        mask &= out_deg[cand_safe] >= plan.min_out[i]
+        mask &= in_deg[cand_safe] >= plan.min_in[i]
         for j in range(i):
-            mask &= cand != emb[:, j][:, None]  # injectivity
+            mask &= cand != emb_t[:, j][:, None]  # injectivity
         return mask
+
+    need_out, need_in = ((plan.check_out, plan.check_in) if checks is None
+                         else checks)
 
     def _edge_checks(cand_safe, prev_rows):
         """prev_rows: (..., k) prefix columns aligned with cand_safe."""
         ok = jnp.ones(cand_safe.shape, bool)
         for j in range(i):  # static unroll over prefix
             prev_safe = jnp.clip(prev_rows[..., j], 0, g.n - 1)
-            co = plan.check_out[i, j]
-            ci = plan.check_in[i, j]
-            ok_out = edge_exists(g.out_indptr, g.out_indices, cand_safe,
-                                 prev_safe, cfg.bisect_iters)
-            ok_in = edge_exists(g.out_indptr, g.out_indices, prev_safe,
-                                cand_safe, cfg.bisect_iters)
-            ok &= jnp.where(co, ok_out, True)
-            ok &= jnp.where(ci, ok_in, True)
+            for need, check, u, v in (
+                    (need_out[i, j], plan.check_out[i, j], cand_safe,
+                     prev_safe),
+                    (need_in[i, j], plan.check_in[i, j], prev_safe,
+                     cand_safe)):
+                hit = jax.lax.cond(
+                    need,
+                    lambda u, v: edge_exists(g.out_indptr, g.out_indices,
+                                             u, v, cfg.bisect_iters),
+                    lambda u, v: jnp.ones(
+                        jnp.broadcast_shapes(u.shape, v.shape), bool), u, v)
+                ok &= jnp.where(check, hit, True)
         return ok
 
-    def chunk_body(c, carry):
-        out_emb, out_count, found, ovf = carry
+    src_row_tile = jnp.arange(T * C, dtype=jnp.int32) // C
+    # two-phase survivor buffer: a tile never yields more than T·C cheap
+    # survivors, and the chunk never keeps more than cap of them
+    n_buf = min(T * C, cap)
+
+    def tile_body(t, c, act, n_active, carry):
+        out_emb, out_count, found, n_phase1 = carry
+        rows_t = jax.lax.dynamic_slice(act, (t * T,), (T,))
+        emb_t = emb[rows_t]
+        start_t = start[rows_t]
+        deg_t = jnp.where(t * T + jnp.arange(T, dtype=jnp.int32) < n_active,
+                          deg[rows_t], 0)
         off = c * C + jnp.arange(C, dtype=jnp.int32)[None, :]          # (1, C)
-        idx = start[:, None] + off                                     # (cap, C)
-        in_deg_range = off < deg[:, None]
-        cand = indices_cat[jnp.clip(idx, 0, indices_cat.shape[0] - 1)]  # (cap, C)
+        idx = start_t[:, None] + off                                   # (T, C)
+        in_deg_range = off < deg_t[:, None]
+        cand = indices_cat[jnp.clip(idx, 0, indices_cat.shape[0] - 1)]  # (T, C)
         cand_safe = jnp.clip(cand, 0, g.n - 1)
-        mask = _cheap_mask(cand, cand_safe, in_deg_range)
-        src_row_grid = jnp.arange(cap * C, dtype=jnp.int32) // C
+        mask = _cheap_mask(emb_t, cand, cand_safe, in_deg_range)
 
         if cfg.two_phase and i > 0:
-            # compact cheap-filter survivors, bisect only those lanes
+            # compact cheap-filter survivors, bisect only those lanes; the
+            # chunk keeps its first cap survivors in (row, position) order
             flat = mask.reshape(-1)
             pos1 = jnp.cumsum(flat).astype(jnp.int32) - 1
-            dest1 = jnp.where(flat & (pos1 < cap), pos1, cap)
-            cand_buf = jnp.zeros((cap + 1,), jnp.int32).at[dest1].set(
-                cand_safe.reshape(-1), mode="drop")[:cap]
-            row_buf = jnp.zeros((cap + 1,), jnp.int32).at[dest1].set(
-                src_row_grid, mode="drop")[:cap]
-            n_phase1 = flat.sum().astype(jnp.int32)
-            n_mid = jnp.minimum(n_phase1, cap)
-            mid_valid = jnp.arange(cap, dtype=jnp.int32) < n_mid
-            prev_rows = emb[row_buf]                                   # (cap, k)
+            keep = flat & (n_phase1 + pos1 < cap)
+            dest1 = jnp.where(keep, pos1, _DROP)
+            cand_buf = jnp.zeros((n_buf + 1,), jnp.int32).at[dest1].set(
+                cand_safe.reshape(-1), mode="drop")[:n_buf]
+            row_buf = jnp.zeros((n_buf + 1,), jnp.int32).at[dest1].set(
+                src_row_tile, mode="drop")[:n_buf]
+            n_mid = keep.sum().astype(jnp.int32)
+            mid_valid = jnp.arange(n_buf, dtype=jnp.int32) < n_mid
+            prev_rows = emb_t[row_buf]                                  # (n_buf, k)
             ok = mid_valid & _edge_checks(cand_buf, prev_rows)
             n_new = ok.sum().astype(jnp.int32)
             pos = jnp.cumsum(ok).astype(jnp.int32) - 1 + out_count
-            dest = jnp.where(ok & (pos < cap), pos, cap)
+            dest = jnp.where(ok & (pos < cap), pos, _DROP)
             rows = prev_rows.at[:, i].set(cand_buf)
             out_emb = out_emb.at[dest].set(rows, mode="drop")
-            ovf |= n_phase1 > cap  # phase-1 drop: results may be incomplete
             return (out_emb, jnp.minimum(out_count + n_new, cap),
-                    found + n_new, ovf)
+                    found + n_new, n_phase1 + flat.sum().astype(jnp.int32))
 
-        mask &= _edge_checks(cand_safe, emb[:, None, :])
+        mask &= _edge_checks(cand_safe, emb_t[:, None, :])
         flat_mask = mask.reshape(-1)
         n_new = flat_mask.sum().astype(jnp.int32)
         pos = jnp.cumsum(flat_mask).astype(jnp.int32) - 1 + out_count
-        dest = jnp.where(flat_mask & (pos < cap), pos, cap)
-        rows = emb[src_row_grid].at[:, i].set(cand.reshape(-1))
+        dest = jnp.where(flat_mask & (pos < cap), pos, _DROP)
+        rows = emb_t[src_row_tile].at[:, i].set(cand.reshape(-1))
         out_emb = out_emb.at[dest].set(rows, mode="drop")
         return (out_emb, jnp.minimum(out_count + n_new, cap),
-                found + n_new, ovf)
+                found + n_new, n_phase1)
 
+    n_chunks = jnp.minimum((jnp.max(deg) + C - 1) // C, cfg.max_chunks)
+
+    def chunk_body(c, carry):
+        out_emb, out_count, found, ovf = carry
+        # the rows whose degree reaches into chunk c, in row order
+        active = deg[:n_rows] > c * C
+        n_active = active.sum().astype(jnp.int32)
+        slot = jnp.cumsum(active).astype(jnp.int32) - 1
+        act = jnp.zeros((n_slots + 1,), jnp.int32).at[
+            jnp.where(active, slot, _DROP)].set(row_ids, mode="drop")
+        out_emb, out_count, found, n_phase1 = jax.lax.fori_loop(
+            0, (n_active + T - 1) // T,
+            lambda t, cr: tile_body(t, c, act, n_active, cr),
+            (out_emb, out_count, found, jnp.int32(0)))
+        # phase-1 drop: results may be incomplete
+        return out_emb, out_count, found, ovf | (n_phase1 > cap)
+
+    out_emb0 = jnp.full((cap + 1, k), -1, dtype=jnp.int32)
     out_emb, out_count, found, ovf = jax.lax.fori_loop(
-        0, cfg.max_chunks, chunk_body,
+        0, n_chunks, chunk_body,
         (out_emb0, jnp.int32(0), jnp.int32(0), jnp.bool_(False)),
     )
     return out_emb[:cap], out_count, found, ovf
 
 
+def batch_checks(plans: PatternPlan):
+    """The edge checks any pattern of a stacked plan batch needs:
+    (out, in) (k, k) bool, for `match_block`'s ``checks``."""
+    return (jnp.any(plans.check_out, axis=0), jnp.any(plans.check_in, axis=0))
+
+
 @functools.partial(jax.jit, static_argnames=("cfg",))
-def match_block(g: DeviceGraph, plan: PatternPlan, block_start, cfg: MatchConfig
+def match_block(g: DeviceGraph, plan: PatternPlan, block_start, cfg: MatchConfig,
+                checks=None
                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray,
                            jnp.ndarray]:
     """Enumerate embeddings rooted in one vertex block.
@@ -365,6 +441,9 @@ def match_block(g: DeviceGraph, plan: PatternPlan, block_start, cfg: MatchConfig
             dimension rather than a per-pattern kernel re-entry.
       block_start: () int32 — first root vertex of this block.
       cfg:  static MatchConfig (hashable; keys the jit cache with k).
+      checks: optional (out, in) (k, k) bool — the edge checks any pattern
+            of a vmapped batch needs (`batch_checks`), passed unbatched so
+            that a check no pattern needs is skipped; None = the plan's own.
 
     Returns (emb, count, found, overflowed, peak):
       emb:    (cap, k) int32 — embeddings in pattern-order columns, row-major
@@ -387,7 +466,7 @@ def match_block(g: DeviceGraph, plan: PatternPlan, block_start, cfg: MatchConfig
     overflowed = jnp.bool_(False)
     for level in range(1, plan.k):
         emb, count, lvl_found, lvl_ovf = _expand_level(
-            g, plan, emb, count, level, cfg)
+            g, plan, emb, count, level, cfg, checks)
         overflowed |= lvl_ovf | (lvl_found > cfg.cap)
         found = lvl_found
         peak = jnp.maximum(peak, count)

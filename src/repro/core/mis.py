@@ -7,7 +7,7 @@ each returns *some* valid independent set of size τ, which may differ
 between the two — exactly the paper's contract, where mIS is any maximal
 set (Fig. 3c vs 3d) and early termination returns any τ-subset (§3.1.1):
 
-  * ``mis_greedy_update`` — a sequential ``lax.scan`` over embedding rows
+  * ``mis_greedy_update`` — a sequential loop over the valid embedding rows
     carrying a packed uint32 used-vertex bitmap (mirrors the paper's shared
     bitmap across VF3 states).  A Pallas kernel version keeps the bitmap
     VMEM-resident (see ``repro.kernels.mis_bitmap``).
@@ -71,23 +71,27 @@ def mis_greedy_update(
     the scatter-add-as-OR trick relies on it). Returns updated
     (bitmap, count).
     """
-    cap = emb.shape[0]
-    rows_valid = jnp.arange(cap, dtype=jnp.int32) < n_valid
+    # the scan stops at the last valid row, or once τ is reached: no later
+    # row can be taken, so the rows it skips would change nothing
+    n_rows = jnp.minimum(n_valid, emb.shape[0]).astype(jnp.int32)
 
-    def body(carry, xs):
-        bm, cnt = carry
-        row, valid = xs
-        vs = jnp.clip(row[:k], 0, None)
+    def cond(carry):
+        r, _, cnt = carry
+        return (r < n_rows) & (cnt < tau)
+
+    def body(carry):
+        r, bm, cnt = carry
+        vs = jnp.clip(emb[r, :k], 0, None)
         words = (vs >> 5).astype(jnp.int32)
         bits = jnp.uint32(1) << (vs & 31).astype(jnp.uint32)
-        free = jnp.all((bm[words] & bits) == 0)
-        take = valid & free & (cnt < tau)
+        take = jnp.all((bm[words] & bits) == 0)
         # distinct vertices ⇒ distinct (word, bit) pairs; under `take` none of
         # the bits are set, so scatter-add of the bit values is exactly OR.
         bm = bm.at[words].add(jnp.where(take, bits, jnp.uint32(0)))
-        return (bm, cnt + take.astype(jnp.int32)), None
+        return r + 1, bm, cnt + take.astype(jnp.int32)
 
-    (bitmap, count), _ = jax.lax.scan(body, (bitmap, count), (emb, rows_valid))
+    _, bitmap, count = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), bitmap, count))
     return bitmap, count
 
 

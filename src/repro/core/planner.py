@@ -147,8 +147,11 @@ class CostModel:
         ``(k−1) · cap · chunk · max_chunks`` candidate lanes, each paying
         the gather/mask/compact pipeline;
       * ``cap · row_time_s`` — the per-frontier-row metric update (the
-        greedy-mIS ``lax.scan`` walks every row of the frontier table;
-        dominant on CPU where scan iteration overhead is large).
+        greedy-mIS loop walks the frontier's rows; dominant on CPU where
+        loop iteration overhead is large).
+
+    Both work terms are upper bounds: the matcher walks only the valid
+    frontier rows and the chunks their degrees need.
 
     ``dispatch_overhead_s`` is everything a step pays regardless of
     geometry: program dispatch, host↔device sync, the host loop's python
@@ -160,13 +163,15 @@ class CostModel:
 
     ``row_time_s`` is fitted on the ``mis`` step; the metric scan term is
     the one constant that genuinely differs across metrics (greedy mIS's
-    ``lax.scan`` vs MNI's scatter-OR vs frac's scatter-add), so schema-2
+    sequential loop vs MNI's scatter-OR vs frac's scatter-add), so schema-2
     calibrations carry optional per-metric overrides
     (``row_time_{mni,frac,luby}_s`` — ``row_time(metric)`` resolves them,
     falling back to the shared constant for schema-1 files and defaults).
     Everything else is metric-independent: the model prices *relative*
     plane/bucket choices, not absolute runtimes.  Defaults are
-    conservative CPU numbers.
+    conservative CPU numbers, and ``backend`` says so: a model prices
+    planes only on the platform it describes (None: a file that does not
+    say — taken to describe the running one).
     """
 
     dispatch_overhead_s: float = 2.0e-3
@@ -183,6 +188,7 @@ class CostModel:
     # files, fresh fits) falls back to the ESCALATION_PRIOR constant.
     escalation_fraction: Optional[float] = None
     source: str = "defaults"
+    backend: Optional[str] = "cpu"
 
     def to_dict(self) -> Dict[str, Any]:
         return {
@@ -196,6 +202,7 @@ class CostModel:
             "row_time_luby_s": self.row_time_luby_s,
             "escalation_fraction": self.escalation_fraction,
             "source": self.source,
+            "backend": self.backend,
         }
 
     @classmethod
@@ -219,6 +226,8 @@ class CostModel:
                 row_time_luby_s=opt("row_time_luby_s"),
                 escalation_fraction=opt("escalation_fraction"),
                 source=str(d.get("source", "file")),
+                backend=(None if d.get("backend") is None
+                         else str(d["backend"])),
             )
         except (TypeError, ValueError):
             return base
@@ -269,13 +278,18 @@ class CostModel:
                 + bucket * cfg.cap * self.row_time(metric) * factor)
 
 
-def load_calibration(path: Optional[str] = None) -> CostModel:
+def load_calibration(path: Optional[str] = None,
+                     device=None) -> CostModel:
     """Load the fitted `CostModel`, falling back to safe defaults.
 
     Search order: explicit ``path`` (exclusively, when given) →
     ``$REPRO_PLANNER_CALIBRATION`` → ``./planner_calibration.json``.  A
     missing or malformed file is never an error — the planner must work
-    out of the box.
+    out of the box.  A file fitted on another device — its ``backend`` or,
+    where recorded, ``device_kind`` differs from ``device`` (default:
+    ``jax.devices()[0]``) — is ignored for the built-in defaults, with a
+    note on stderr (once per file and reason): CPU timings must not price
+    planes on a TPU.
     """
     env = os.environ.get(CALIBRATION_ENV)
     candidates = [path] if path is not None else [env,
@@ -300,19 +314,41 @@ def load_calibration(path: Optional[str] = None) -> CostModel:
             if d is not None and d.get("schema") not in CALIBRATION_SCHEMAS:
                 problem = (f"schema {d.get('schema')!r} not in "
                            f"{CALIBRATION_SCHEMAS}")
+        mismatch = _device_mismatch(d, device) if problem is None else None
+        # an explicitly requested file that can't be used does NOT fall
+        # through to whatever file happens to sit in cwd
+        if mismatch is not None or (problem is not None and cand in explicit):
+            note = (f"[planner] ignoring calibration {cand}: "
+                    f"{mismatch or problem}; using built-in defaults")
+            if note not in _NOTED:
+                _NOTED.add(note)
+                print(note, file=sys.stderr)
+            return CostModel()
         if problem is not None:
-            if cand in explicit:
-                # do NOT fall through to whatever file happens to sit in
-                # cwd — the user asked for this one specifically
-                print(f"[planner] ignoring calibration {cand}: {problem}; "
-                      f"using built-in defaults", file=sys.stderr)
-                return CostModel()
             continue
         # `source` records provenance-as-loaded: the path wins over any
         # source the file itself carries (calibrate.py writes "fit")
         d["source"] = str(p)
         return CostModel.from_dict(d)
     return CostModel()
+
+
+_NOTED: set = set()   # stderr notes already given by load_calibration
+
+
+def _device_mismatch(d: Dict[str, Any], dev=None) -> Optional[str]:
+    """Why calibration ``d`` does not describe device ``dev`` (default:
+    ``jax.devices()[0]``), or None."""
+    if dev is None:
+        import jax
+
+        dev = jax.devices()[0]
+    for key, running in (("backend", dev.platform),
+                         ("device_kind", dev.device_kind)):
+        fitted = d.get(key)
+        if fitted is not None and fitted != running:
+            return f"fitted for {key} {fitted!r}, running on {running!r}"
+    return None
 
 
 def persist_escalation_fraction(fraction: float,
@@ -323,15 +359,18 @@ def persist_escalation_fraction(fraction: float,
     back to ``ESCALATION_PRIOR`` when a level has no telemetry; persisting
     the measured fraction (schema 3) warm-starts the next run's prior from
     real data.  EMA with weight 0.5 against any existing value smooths
-    run-to-run noise.  Resolution mirrors `load_calibration` (argument →
-    env → cwd default); schema-1/2 files are upgraded in place, other
-    existing constants are preserved, and any I/O or parse problem is
-    swallowed (calibration is an optimization, never a correctness input).
-    Returns the path written, or None.
+    run-to-run noise.  Only a file named explicitly (``path``, else
+    ``$REPRO_PLANNER_CALIBRATION``) is written — never the cwd default,
+    whose drift would make two runs of one query plan differently.
+    Schema-1/2 files are upgraded in place, other existing constants are
+    preserved, and any I/O or parse problem is swallowed (calibration is an
+    optimization, never a correctness input).  Returns the path written,
+    or None.
     """
     frac = min(1.0, max(0.0, float(fraction)))
-    target = path or os.environ.get(CALIBRATION_ENV) \
-        or DEFAULT_CALIBRATION_FILE
+    target = path or os.environ.get(CALIBRATION_ENV)
+    if not target:
+        return None
     p = Path(target)
     d: Dict[str, Any] = {}
     if p.is_file():
@@ -473,11 +512,19 @@ class ExecutionPlanner:
 
     def __init__(self, g: DataGraph, cfg, *,
                  cost_model: Optional[CostModel] = None,
-                 n_devices: int = 1):
+                 n_devices: int = 1, platform: Optional[str] = None):
         self.g = g
         self.cfg = cfg
         self.cost = cost_model or load_calibration()
         self.n_devices = max(1, int(n_devices))
+        if platform is None:
+            import jax
+
+            platform = jax.devices()[0].platform
+        # constants fitted on another platform (the built-in ones are CPU
+        # guesses) cannot compare planes here: auto then keeps the default
+        # batched plane and only derives result-preserving geometry
+        self.priced = self.cost.backend in (None, platform)
         self.block_order = root_block_order(
             g, cfg.match.root_block, getattr(cfg, "root_order", "degree"))
         self.n_blocks = int(self.block_order.shape[0])
@@ -565,6 +612,8 @@ class ExecutionPlanner:
         mesh-invariant super-block schedule (``cfg.blocks_per_super``) and
         the metric is ``mis_luby`` — without those, auto silently changing
         accounting granularity would break the forced-plane equivalence.
+        A cost model not fitted for the running platform prices nothing:
+        auto then runs the batched plane.
         """
         cfg = self.cfg
         if cfg.execution == "sampled":
@@ -585,6 +634,9 @@ class ExecutionPlanner:
         for p in patterns:
             by_k[p.k] = by_k.get(p.k, 0) + 1
         max_batch = self.choose_bucket(max(by_k.values()))
+        if not self.priced:
+            return LevelPlan(plane="batched", match=match,
+                             max_batch=max_batch)
         sizes = sorted(by_k.items())
         costs = self._level_costs([(sz, k) for k, sz in sizes], match,
                                   max_batch)

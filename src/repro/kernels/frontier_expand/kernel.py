@@ -22,12 +22,12 @@ whose grid carries the pattern axis, instead of re-entering the kernel per
 pattern.  The kernel body is grid-index-free, which keeps that transform
 sound.
 
-Lowering note: the body uses vector gathers (CSR rows, labels) and a
-scatter-compaction; Mosaic support for these lowerings varies by TPU
-generation/jaxlib.  Correctness is guaranteed in interpret mode
-(``interpret=True``, the default on this CPU container) and
-property-tested against the XLA pipeline; ``docs/kernels.md`` documents
-the fallback rule.
+Lowering note: the body uses 1-D vector gathers (CSR rows, labels) and a
+scatter-compaction.  Mosaic (jaxlib 0.9.0) refuses them for TPU v5e
+("Only 2D gather is supported"), so the kernel runs only in interpret mode,
+off-TPU, where it is property-tested against the XLA pipeline;
+``ops.interpret_mode`` refuses it on a TPU and ``docs/kernels.md`` states
+the rule.
 
 VMEM budget: the graph CSR arrays plus two (cap, k) frontier tiles plus
 the transient (cap·chunk, k) candidate rows must fit in VMEM (~16 MB/core)

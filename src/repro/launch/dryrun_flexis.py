@@ -18,9 +18,7 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro import jax_compat
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import flexis_paper as FP
 from repro.core.graph import DeviceGraph
@@ -75,7 +73,8 @@ def main(argv=None) -> int:
         mesh = make_production_mesh(multi_pod=mp)
         ndev = mesh_device_count(mesh)
         axis = "roots"
-        flat = jax_compat.make_raw_mesh(mesh.devices.reshape(-1), (axis,))
+        flat = Mesh(mesh.devices.reshape(-1), (axis,),
+                    axis_types=(AxisType.Auto,))
         cfg = MatchConfig(cap=FP.MATCH_CAP, root_block=FP.ROOT_BLOCK,
                           chunk=FP.CHUNK, max_chunks=FP.MAX_CHUNKS,
                           bisect_iters=FP.BISECT_ITERS)

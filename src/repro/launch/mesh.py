@@ -8,16 +8,15 @@ from typing import Optional, Tuple
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
-
-from repro import jax_compat
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16×16 = 256 chips per pod; 2×16×16 = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax_compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh_for_devices(n: Optional[int] = None,
@@ -25,8 +24,9 @@ def make_mesh_for_devices(n: Optional[int] = None,
     """Small-scale mesh for local runs/tests: (n/model, model)."""
     n = n if n is not None else len(jax.devices())
     assert n % model_parallel == 0
-    return jax_compat.make_mesh(
-        (n // model_parallel, model_parallel), ("data", "model"))
+    return jax.make_mesh(
+        (n // model_parallel, model_parallel), ("data", "model"),
+        axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_device_count(mesh: Mesh) -> int:

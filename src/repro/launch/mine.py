@@ -22,6 +22,7 @@ import time
 from repro.core import MatchConfig, MiningConfig, mine
 from repro.core.flexis import tau_threshold
 from repro.data.synthetic import PAPER_DATASETS, paper_dataset
+from repro.launch.compile_cache import enable_compile_cache
 
 # distinct "preempted, resumable" status: the run was stopped on request
 # (SIGTERM/SIGINT) after committing a final snapshot — rerunning the same
@@ -90,12 +91,8 @@ def main(argv=None) -> int:
                          "single-phase xla pipeline (when a level overflows "
                          "cap, truncation content may differ from the "
                          "two-phase xla pipeline; overflow is always "
-                         "flagged)")
-    ap.add_argument("--pallas-interpret", default="auto",
-                    choices=["auto", "on", "off"],
-                    help="run the Pallas kernel in interpret mode: auto = "
-                         "off on TPU, on elsewhere (interpret is required "
-                         "off-TPU; the fused lowering only exists on TPU)")
+                         "flagged); interpreted off-TPU, refused on a TPU "
+                         "(Mosaic cannot lower it yet)")
     ap.add_argument("--root-block", type=int, default=None,
                     help="root-block width override (default: sized by "
                          "MatchConfig.for_graph).  The sampled plane draws "
@@ -122,6 +119,7 @@ def main(argv=None) -> int:
                          "still picked up when present; --resume makes a "
                          "missing one an error instead of a fresh start)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.resume and not args.checkpoint_dir:
         ap.error("--resume requires --checkpoint-dir")
@@ -143,11 +141,6 @@ def main(argv=None) -> int:
 
     import dataclasses as _dc
 
-    import jax as _jax
-
-    interpret = (_jax.default_backend() != "tpu"
-                 if args.pallas_interpret == "auto"
-                 else args.pallas_interpret == "on")
     cfg = MiningConfig(
         sigma=args.sigma, lam=args.lam, metric=args.metric,
         generation=args.generation, max_pattern_size=args.max_size,
@@ -157,7 +150,6 @@ def main(argv=None) -> int:
         sample_seed=args.sample_seed, sample_rounds=args.sample_rounds,
         match=_dc.replace(
             MatchConfig.for_graph(g, cap=args.cap, expansion=args.expansion),
-            pallas_interpret=interpret,
             **({"root_block": args.root_block}
                if args.root_block is not None else {})),
     )
@@ -200,7 +192,7 @@ def main(argv=None) -> int:
     else:
         res = mine(g, cfg)
 
-    print(f"[mine] done in {res.elapsed_s:.2f}s"
+    print(f"[mine] done in {res.elapsed_s:.2f}s on {res.device}"
           f"{' (TIMED OUT)' if res.timed_out else ''}")
     print(f"[mine] frequent patterns: {len(res.frequent)}  "
           f"searched: {res.searched}  peak device bytes: "
@@ -221,9 +213,11 @@ def main(argv=None) -> int:
         print(f"[mine]   … and {len(res.frequent) - 10} more")
 
     # warm-start future pricing: fold the measured escalation fraction of
-    # this run's sampled levels into the calibration file (schema 3) —
-    # the planner's `esc_prior()` reads it back instead of the built-in
-    # ESCALATION_PRIOR constant
+    # this run's sampled levels into the calibration file named by
+    # --calibration or $REPRO_PLANNER_CALIBRATION (schema 3) — the
+    # planner's `esc_prior()` reads it back instead of the built-in
+    # ESCALATION_PRIOR constant.  The tracked default file is never
+    # rewritten, so two runs of one query plan alike.
     samp = [v["sampled"] for v in res.per_level.values()
             if isinstance(v.get("sampled"), dict)
             and not v["sampled"].get("exact", False)]
@@ -246,6 +240,7 @@ def main(argv=None) -> int:
             "elapsed_s": res.elapsed_s, "timed_out": res.timed_out,
             "n_frequent": len(res.frequent), "searched": res.searched,
             "peak_device_bytes": res.peak_device_bytes,
+            "device": res.device,
             "dispatches": sum(int(v.get("dispatches", 0))
                               for v in res.per_level.values()),
             # sampled plane: escalations across levels (per-level detail —

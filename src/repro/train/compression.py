@@ -13,8 +13,6 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import jax_compat
-
 __all__ = ["CompressionState", "compression_init", "compress_tree",
            "decompress_tree", "compressed_psum"]
 
@@ -73,7 +71,7 @@ def compressed_psum(grads: Params, axis: str,
     q32 = jax.tree_util.tree_map(lambda x: x.astype(jnp.int32), q)
     q_sum = jax.lax.psum(q32, axis)
     s_mean = jax.lax.pmean(s, axis)
-    n = jax_compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     out = jax.tree_util.tree_map(
         lambda qq, ss: qq.astype(jnp.float32) * ss / n, q_sum, s_mean)
     return out, state

@@ -24,9 +24,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-from repro import jax_compat
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["best_mesh", "StragglerTimeout", "StepGuard", "HeartbeatFile",
            "resume_or_init"]
@@ -46,8 +44,9 @@ def best_mesh(n_devices: Optional[int] = None, *,
     while model * 2 <= prefer_model and n % (model * 2) == 0:
         model *= 2
     data = n // model
-    return jax_compat.make_mesh(
-        (data, model), ("data", "model"), devices=np.array(devs[:n]))
+    return jax.make_mesh(
+        (data, model), ("data", "model"),
+        axis_types=(AxisType.Auto,) * 2, devices=np.array(devs[:n]))
 
 
 @dataclasses.dataclass

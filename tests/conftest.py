@@ -1,4 +1,7 @@
 """Shared fixtures/strategies. NOTE: no XLA_FLAGS here — tests see 1 device."""
+import gc
+
+import jax
 import numpy as np
 import pytest
 
@@ -11,6 +14,31 @@ except ModuleNotFoundError:
 from hypothesis import strategies as st
 
 from repro.core import Pattern, build_graph
+
+
+# Each XLA:CPU executable holds a few memory mappings for as long as JAX
+# caches it.  A test worker that compiles thousands of programs reaches the
+# kernel's vm.max_map_count (65530 by default) and dies inside the compiler
+# with a segfault or an abort; one hypothesis test can add ~11,000.
+_MAPPINGS_BEFORE_RELEASE = 30000
+
+
+def _mapping_count() -> int:
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return sum(1 for _ in f)
+    except OSError:  # no procfs: nothing to watch
+        return 0
+
+
+@pytest.fixture(autouse=True)
+def _release_compiled_programs():
+    """Drop every compiled program once the process holds too many
+    mappings (tests that follow recompile what they need)."""
+    yield
+    if _mapping_count() > _MAPPINGS_BEFORE_RELEASE:
+        jax.clear_caches()
+        gc.collect()
 
 
 # ---------------------------------------------------------------------------

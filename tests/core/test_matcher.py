@@ -1,6 +1,7 @@
 """JAX frontier matcher vs the brute-force host oracle (property tests)."""
 import numpy as np
 import jax.numpy as jnp
+import pytest
 from hypothesis import given, settings, assume, HealthCheck
 
 from repro.core import MatchConfig, make_plan, match_block
@@ -108,3 +109,96 @@ def test_directed_vs_bidirectional_patterns():
     got_b, _, _ = _all_embeddings(g, pat_bwd, cfg)
     assert got_f.shape[0] == 0
     assert got_b.shape[0] == 1
+
+
+@pytest.mark.parametrize("two_phase,cap,n_labels", [
+    (False, 512, 3), (True, 512, 3), (False, 24, 1), (True, 24, 1)])
+def test_row_tiles_do_not_change_results(monkeypatch, two_phase, cap,
+                                         n_labels):
+    """The expansion walks the frontier in row tiles; any tile height gives
+    the untiled result — rows, order, count, found and overflow (cap 24 on
+    one label overflows, in phase 1 of the two-phase path too)."""
+    import dataclasses
+
+    import jax
+
+    from repro.core import build_graph, matcher
+    from repro.core.flexis import initial_candidates
+    from repro.core.generation import generate_new_patterns
+
+    rng = np.random.default_rng(11)
+    n = 300
+    src = rng.integers(0, n, 5 * n)
+    dst = rng.integers(0, n, 5 * n)
+    g = build_graph(n, np.stack([src, dst], 1),
+                    rng.integers(0, n_labels, n), undirected=True)
+    dev = DeviceGraph.from_host(g)
+    cfg = dataclasses.replace(
+        MatchConfig.for_graph(g, cap=4096, root_block=128, chunk=4),
+        cap=cap, two_phase=two_phase)
+    pats = generate_new_patterns(initial_candidates(g)[:6])[:3]
+    assert pats and matcher._row_tile(cfg) >= cfg.cap  # one tile by default
+
+    def run(plan):
+        # a fresh jit: the tile height is read at trace time
+        return jax.jit(lambda s: match_block.__wrapped__(dev, plan, s, cfg))(
+            jnp.int32(0))
+
+    ref = [run(make_plan(p, g)) for p in pats]
+    monkeypatch.setattr(matcher, "TILE_LANES", 5 * cfg.chunk)
+    assert matcher._row_tile(cfg) == 5
+    for p, want in zip(pats, ref):
+        got = run(make_plan(p, g))
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if cap == 24:
+        assert any(bool(r[3]) for r in ref), "cap 24 was meant to overflow"
+
+
+
+@pytest.mark.parametrize("two_phase", [False, True])
+def test_batch_checks_skip_only_unneeded_edge_checks(two_phase):
+    """A vmapped batch skips the edge checks none of its patterns needs:
+    per-pattern results equal the unbatched plan-driven match and the host
+    oracle, for a batch that needs none (single edges), some, and all."""
+    import dataclasses
+
+    import jax
+
+    from repro.core import build_graph
+    from repro.core.flexis import initial_candidates
+    from repro.core.generation import generate_new_patterns
+    from repro.core.matcher import batch_checks
+    from repro.core.plan import stack_plans
+
+    rng = np.random.default_rng(5)
+    n = 200
+    g = build_graph(n, rng.integers(0, n, (6 * n, 2)),
+                    rng.integers(0, 2, n), undirected=False)
+    dev = DeviceGraph.from_host(g)
+    cfg = dataclasses.replace(
+        MatchConfig.for_graph(g, cap=8192, root_block=256, chunk=4),
+        two_phase=two_phase)
+    edges = initial_candidates(g)
+    k3 = [p for p in generate_new_patterns(edges) if p.k == 3]
+    need = lambda p: bool(np.any(np.asarray(make_plan(p, g).check_out)  # noqa: E731
+                                 | np.asarray(make_plan(p, g).check_in)))
+    batches = [[p for p in edges if not need(p)][:3],
+               [p for p in k3 if not need(p)][:2] + [p for p in k3 if need(p)][:2],
+               [p for p in edges if need(p)][:2]]
+    for pats in batches:
+        assert pats
+        plans = stack_plans([make_plan(p, g) for p in pats])
+        got = jax.vmap(lambda pl: match_block(
+            dev, pl, jnp.int32(0), cfg, batch_checks(plans)))(plans)
+        for i, p in enumerate(pats):
+            plan = make_plan(p, g)
+            want = match_block(dev, plan, jnp.int32(0), cfg)
+            for a, b in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(a)[i], np.asarray(b))
+            # one root block holds every root: all embeddings, none dropped
+            assert not bool(got[3][i])
+            rows = np.asarray(got[0][i])[:int(got[1][i])]
+            rows = rows[:, np.argsort(np.array(plan.order))]
+            assert set(map(tuple, rows.tolist())) == set(
+                map(tuple, enumerate_embeddings_host(g, p).tolist()))

@@ -163,6 +163,31 @@ def test_plane_decision_regimes():
         assert plan.max_batch == cfg.batch_patterns
 
 
+@pytest.mark.parametrize("backend,platform,priced", [
+    ("cpu", "cpu", True),
+    (None, "tpu", True),
+    ("cpu", "tpu", False),
+    ("tpu", "tpu", True),
+])
+def test_unpriced_platform_plans_batched(backend, platform, priced):
+    """Constants fitted on another platform compare no planes: auto keeps
+    the batched plane (still deriving geometry) even where the model would
+    pick sequential."""
+    g = rmat_graph(128, 700, n_labels=2, seed=1, undirected=True)
+    cfg = MiningConfig(sigma=3, execution="auto",
+                       match=MatchConfig.for_graph(g, cap=1024,
+                                                   root_block=32))
+    planner = ExecutionPlanner(
+        g, cfg, cost_model=CostModel(backend=backend), platform=platform)
+    assert planner.priced is priced
+    cands = initial_candidates(g)
+    plan = planner.plan_level(1, cands[:1], [2])
+    assert plan.plane == ("sequential" if priced else "batched")
+    prev = {"max_count": 10, "overflowed": False}
+    assert planner.plan_level(2, cands[:1], [2], prev=prev).match.cap \
+        == CAP_FLOOR
+
+
 def test_distributed_gating():
     """Auto may pick distributed only with metric=mis_luby, >1 device AND a
     pinned mesh-invariant super-block schedule."""
@@ -303,6 +328,34 @@ def test_load_calibration(tmp_path, monkeypatch):
     # dict round-trip (what the session pins in snapshots)
     assert CostModel.from_dict(cm.to_dict()) == dataclasses.replace(
         cm, source=cm.source)
+    assert cm.backend is None   # the file names no backend
+    assert CostModel.from_dict(CostModel().to_dict()).backend == "cpu"
+
+
+@pytest.mark.parametrize("fitted,loads", [
+    ({"backend": "cpu", "device_kind": "cpu"}, True),
+    ({"backend": "cpu"}, True),
+    ({"backend": "tpu"}, False),
+    ({"backend": "cpu", "device_kind": "TPU v5 lite"}, False),
+])
+def test_load_calibration_only_for_its_device(tmp_path, monkeypatch, capsys,
+                                              fitted, loads):
+    """A calibration fitted on another backend or device kind prices
+    nothing: the planner falls back to the defaults and says so."""
+    monkeypatch.delenv(CALIBRATION_ENV, raising=False)
+    p = tmp_path / "cal.json"
+    p.write_text(json.dumps({"schema": 3, "lane_time_s": 7e-9, **fitted}))
+    cm = load_calibration(str(p))
+    if loads:
+        assert cm.lane_time_s == 7e-9
+        assert "ignoring" not in capsys.readouterr().err
+    else:
+        assert cm == CostModel()
+        assert "fitted for" in capsys.readouterr().err
+    # the cwd default is held to the same rule
+    monkeypatch.chdir(tmp_path)
+    p.rename(tmp_path / "planner_calibration.json")
+    assert (load_calibration().lane_time_s == 7e-9) is loads
 
 
 def test_config_validation():

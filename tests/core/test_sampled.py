@@ -513,6 +513,25 @@ def test_persist_escalation_fraction_ema_and_schema_upgrade(tmp_path):
     assert cm.esc_prior() == pytest.approx(0.3)
 
 
+def test_persist_escalation_fraction_only_into_named_file(tmp_path,
+                                                        monkeypatch):
+    """The tracked cwd default is never rewritten; the env-named file is."""
+    from repro.core.planner import persist_escalation_fraction
+
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.delenv(CALIBRATION_ENV, raising=False)
+    default = tmp_path / "planner_calibration.json"
+    default.write_text(json.dumps({"schema": 3, "lane_time_s": 1e-9}))
+    before = default.read_text()
+    assert persist_escalation_fraction(0.5) is None
+    assert default.read_text() == before
+    named = tmp_path / "named.json"
+    monkeypatch.setenv(CALIBRATION_ENV, str(named))
+    assert persist_escalation_fraction(0.5) == str(named)
+    assert json.loads(named.read_text())["escalation_fraction"] == 0.5
+    assert default.read_text() == before
+
+
 def test_schema12_load_leaves_prior_at_constant(tmp_path, monkeypatch):
     from repro.core.planner import ESCALATION_PRIOR
     f = tmp_path / "s2.json"

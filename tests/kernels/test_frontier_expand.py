@@ -1,7 +1,7 @@
 """Fused frontier-expansion kernel: parity vs ref vs the XLA pipeline.
 
 Three implementations must agree bit-for-bit (single-phase semantics):
-  * the Pallas kernel (interpret mode on this CPU container),
+  * the Pallas kernel (interpret mode: it runs only off-TPU),
   * `ref.frontier_expand_ref` (the single-phase XLA pipeline),
   * `match_block` with expansion="xla", two_phase=False.
 Coverage includes edgeless graphs, cap-overflow truncation, the two-phase
@@ -209,6 +209,18 @@ def test_expansion_validation():
     with pytest.raises(ValueError):
         MatchConfig(expansion="fused")
     assert MatchConfig(expansion="pallas").expansion == "pallas"
+
+
+def test_pallas_config_refused_on_tpu(monkeypatch):
+    """Off-TPU the kernel is interpreted; on a TPU, where Mosaic cannot
+    lower it, building the config raises instead of interpreting there."""
+    from repro.kernels.frontier_expand import ops
+
+    assert ops.interpret_mode() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="Only 2D gather"):
+        MatchConfig(expansion="pallas")
+    assert MatchConfig(expansion="xla").expansion == "xla"
 
 
 def test_two_phase_normalized_off_on_pallas_plane():

@@ -7,16 +7,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from repro import jax_compat
 from repro.launch.dryrun import (
     _fix_divisibility, collective_bytes_from_hlo,
 )
 
 
 def _mesh(shape, names):
-    return jax_compat.make_mesh(shape, names)
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def test_collective_parser_counts_psum():
@@ -27,8 +27,8 @@ def test_collective_parser_counts_psum():
 
     x = jnp.ones((128, 64), jnp.float32)
     hlo = (
-        jax.jit(jax_compat.shard_map(f, mesh=mesh, in_specs=(P(),),
-                                     out_specs=P()))
+        jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P(),),
+                              out_specs=P(), check_vma=False))
         .lower(x).compile().as_text())
     stats = collective_bytes_from_hlo(hlo)
     assert stats["count"] >= 1
@@ -84,10 +84,10 @@ def test_constrain_divisibility_guard():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         import jax, jax.numpy as jnp
-        from repro import jax_compat
         from repro.models.sharding import constrain, use_rules
 
-        mesh = jax_compat.make_mesh((1, 2), ("data", "model"))
+        mesh = jax.make_mesh((1, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         with use_rules(mesh):
             @jax.jit
             def f(x):

@@ -327,9 +327,7 @@ def test_restore_with_shardings(tmp_path):
 
     t = _tree()
     ckpt.save(tmp_path, 0, t)
-    from repro import jax_compat
-
-    mesh = jax_compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     sh = jax.tree_util.tree_map(
         lambda _: NamedSharding(mesh, P()), jax.eval_shape(lambda: t))
     got, _, _ = ckpt.restore(tmp_path, jax.eval_shape(lambda: t),

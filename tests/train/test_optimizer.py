@@ -49,9 +49,8 @@ def test_global_norm():
 def test_zero1_specs_moves_to_data_axis():
     from jax.sharding import PartitionSpec as P
 
-    from repro import jax_compat
-
-    mesh = jax_compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     # data axis size 1 → no change
     specs = {"w": P(None, "model")}
     abst = {"w": jax.ShapeDtypeStruct((16, 8), jnp.float32)}
