@@ -75,33 +75,18 @@ def check(cond: bool, msg: str) -> None:
         raise Failed(msg)
 
 
-class CompileCounter:
-    """Backend compiles and persistent-cache hits, from JAX's monitoring
-    events.  A cache hit still reports a (short) backend-compile event."""
+def compiles_since(snap) -> str:
+    """Backend compiles and persistent-cache hits since ``snap``, from the
+    program's own compile counter (`repro.core.tracing`)."""
+    from repro.core import tracing
 
-    def __init__(self, jax):
-        self.n = 0
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
+    return str(tracing.compile_totals() - snap)
 
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.n += 1
-            self.seconds += duration
 
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
+def compile_snapshot():
+    from repro.core import tracing
 
-    def snapshot(self):
-        return self.n, self.seconds, self.cache_hits
-
-    def since(self, snap) -> str:
-        n, s, h = snap
-        return (f"backend compiles {self.n - n} ({self.seconds - s:.1f} s, "
-                f"{self.cache_hits - h} from the persistent cache)")
+    return tracing.compile_totals()
 
 
 def peak_bytes(device) -> int:
@@ -152,10 +137,10 @@ def programs() -> int:
     return batched.program_cache_stats().misses
 
 
-def run_twice(mine, g, cfg, counter, label):
+def run_twice(mine, g, cfg, label):
     """Mine once cold (compiles included) and once warm; returns the warm
     result after checking both agree."""
-    snap = counter.snapshot()
+    snap = compile_snapshot()
     t0 = time.monotonic()
     first = mine(g, cfg)
     t1 = time.monotonic()
@@ -163,12 +148,12 @@ def run_twice(mine, g, cfg, counter, label):
     t2 = time.monotonic()
     check(supports(first) == supports(warm), f"{label}: warm run differs")
     say(f"  {label}: first {t1 - t0:.2f} s, warm {t2 - t1:.2f} s, "
-        f"{counter.since(snap)}; {len(warm.frequent)} frequent of "
+        f"{compiles_since(snap)}; {len(warm.frequent)} frequent of "
         f"{warm.searched} searched on {warm.device}")
     return warm
 
 
-def reference_phase(jax, counter) -> None:
+def reference_phase(jax) -> None:
     """Small exact case: TPU auto ≡ TPU sequential ≡ host CPU."""
     from repro.core import MatchConfig, MiningConfig, mine
     from repro.data.synthetic import paper_dataset
@@ -190,7 +175,7 @@ def reference_phase(jax, counter) -> None:
             ctx = (jax.default_device(device) if device is not None
                    else contextlib.nullcontext())
             with ctx:
-                res = run_twice(mine, g, cfg, counter,
+                res = run_twice(mine, g, cfg,
                                 f"{metric} sigma={cfg.sigma} {label}")
             want = "cpu" if device is not None else "tpu"
             check(res.device.startswith(want + ":"),
@@ -206,29 +191,29 @@ def reference_phase(jax, counter) -> None:
         if metric == "mis":
             # the sequential plane compiles a handful of programs: enough
             # to show the disk cache without re-tracing auto's hundred
-            cache_check(jax, counter, mine, g,
+            cache_check(jax, mine, g,
                         dataclasses.replace(base, execution="sequential"),
                         got["tpu auto"])
 
 
-def cache_check(jax, counter, mine, g, cfg, want) -> None:
+def cache_check(jax, mine, g, cfg, want) -> None:
     """The persistent compile cache: with every compiled program dropped
     from memory, mining again must load its programs from the disk cache."""
     from repro.core import batched
 
     jax.clear_caches()
     batched.clear_program_cache()
-    snap = counter.snapshot()
+    snap = compile_snapshot()
     t0 = time.monotonic()
     res = mine(g, cfg)
     check(supports(res) == want, "rerun after clearing caches differs")
     say(f"  mis tpu sequential again, in-memory caches cleared: "
-        f"{time.monotonic() - t0:.2f} s, {counter.since(snap)}")
-    check(counter.cache_hits > snap[2],
+        f"{time.monotonic() - t0:.2f} s, {compiles_since(snap)}")
+    check(compile_snapshot().cache_hits > snap.cache_hits,
           "no program came from the persistent compile cache")
 
 
-def mico_phase(jax, counter, deadline: float) -> None:
+def mico_phase(jax, deadline: float) -> None:
     """The paper's largest graph through the command-line entry point."""
     from repro.launch import mine as mine_cli
 
@@ -242,14 +227,14 @@ def mico_phase(jax, counter, deadline: float) -> None:
             "--cap", str(MICO["cap"]),
             "--time-limit", f"{limit:.0f}", "--json", str(out)]
     say("real size: python -m repro.launch.mine " + " ".join(argv))
-    snap, n_prog = counter.snapshot(), programs()
+    snap, n_prog = compile_snapshot(), programs()
     t0 = time.monotonic()
     rc = mine_cli.main(argv)
     wall = time.monotonic() - t0
     check(rc == 0, f"repro.launch.mine exited {rc}")
     d = json.loads(out.read_text())
     say(f"  mico: wall {wall:.2f} s (mining {d['elapsed_s']:.2f} s, graph "
-        f"build and candidate set-up the rest), {counter.since(snap)}, "
+        f"build and candidate set-up the rest), {compiles_since(snap)}, "
         f"{programs() - n_prog} batched step programs")
     say(f"  mico: {d['n_frequent']} frequent of {d['searched']} searched, "
         f"{d['dispatches']} dispatches, per level (candidates, frequent, "
@@ -269,7 +254,7 @@ def mico_phase(jax, counter, deadline: float) -> None:
         f"{d['peak_device_bytes']}")
 
 
-def distributed_phase(jax, counter) -> None:
+def distributed_phase(jax) -> None:
     """mis_luby on a 4-chip mesh against the batched plane on one chip."""
     from repro.core import MatchConfig, MiningConfig, mine
     from repro.core.distributed import mining_mesh
@@ -291,7 +276,7 @@ def distributed_phase(jax, counter) -> None:
     got = {}
     for execution in ("distributed", "batched"):
         cfg = dataclasses.replace(base, execution=execution)
-        snap = counter.snapshot()
+        snap = compile_snapshot()
         t0 = time.monotonic()
         res = mine(g, cfg)
         wall = time.monotonic() - t0
@@ -302,7 +287,7 @@ def distributed_phase(jax, counter) -> None:
         dispatches = sum(int(v.get("dispatches", 0))
                          for v in res.per_level.values())
         say(f"  mis_luby {execution}: wall {wall:.2f} s, "
-            f"{counter.since(snap)}; {len(res.frequent)} frequent of "
+            f"{compiles_since(snap)}; {len(res.frequent)} frequent of "
             f"{res.searched} searched, {dispatches} dispatches, "
             f"{programs()} batched step programs so far")
         got[execution] = supports(res)
@@ -341,21 +326,24 @@ def main(argv=None) -> int:
     devices = device_check(jax)
     OUT.mkdir(exist_ok=True)
     say(f"compile cache: {cache_dir}")
-    counter = CompileCounter(jax)
+    from repro.core import tracing
+
+    tracing.watch_compiles()
     calibration_line(jax)
     try:
         if args.chips == 4:
-            distributed_phase(jax, counter)
+            distributed_phase(jax)
         else:
             t0 = time.monotonic()
-            reference_phase(jax, counter)
+            reference_phase(jax)
             say(f"reference phase: {time.monotonic() - t0:.2f} s; device "
                 f"peak {peak_bytes(devices[0])} bytes in use")
-            mico_phase(jax, counter, t_start + BUDGET_S)
+            mico_phase(jax, t_start + BUDGET_S)
     except Failed as e:
         print(f"[smoke] FAILED: {e}", file=sys.stderr, flush=True)
         return 1
-    say(f"total {time.monotonic() - t_start:.2f} s, {counter.since((0, 0.0, 0))}")
+    say(f"total {time.monotonic() - t_start:.2f} s, "
+        f"{compiles_since(tracing.CompileTotals())}")
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,
         "count": len(devices)}}))

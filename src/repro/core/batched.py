@@ -57,7 +57,8 @@ from .graph import DataGraph, DeviceGraph
 from .pattern import Pattern
 from .plan import PatternPlan, make_plan, stack_plans
 from .matcher import (MatchConfig, batch_checks, match_block,
-                      transient_match_bytes)
+                      match_block_lanes, transient_match_bytes)
+from . import tracing
 from . import mis as mis_lib
 from . import metrics as metrics_lib
 
@@ -96,7 +97,7 @@ def _step_fn(metric: str, k: int, cfg: MatchConfig, unbatched: bool = False,
 
     Signature of the returned callable:
         step(dev_g, plans, block_start, state, taus)
-            -> (state', values, found, overflowed, peaks)
+            -> (state', values, found, overflowed, peaks, lanes)
     With ``capture=True`` two more outputs are appended — ``emb`` (P, cap,
     k) int32 and ``n_valid`` (P,) int32, `match_block`'s raw embedding
     table — which the sampled plane records per (pattern, block) so exact
@@ -117,6 +118,9 @@ def _step_fn(metric: str, k: int, cfg: MatchConfig, unbatched: bool = False,
       overflowed: (P,) bool frontier-capacity flags.
       peaks:   (P,) int32 max frontier occupancy inside the block
                (`match_block`'s peak — the planner's cap-sizing signal).
+      lanes:   (P, 2) int32 expansion lanes walked and lanes that passed
+               every filter (`match_block_lanes`; zeros on the Pallas
+               plane) — the ``lanes_*`` counters of `core/tracing.py`.
 
     ``unbatched=True`` compiles the P == 1 bucket *without* the vmap: the
     math is identical (size-1 batch), but XLA fuses the unbatched op chain
@@ -129,7 +133,7 @@ def _step_fn(metric: str, k: int, cfg: MatchConfig, unbatched: bool = False,
     if metric in ("mis", "mis_luby"):
 
         def step_one(g, plan, block_start, bm, cnt, tau, checks=None):
-            emb, n_valid, found, ovf, peak = match_block(
+            emb, n_valid, found, ovf, peak, lanes = match_block_lanes(
                 g, plan, block_start, cfg, checks)
             if metric == "mis":
                 bm, cnt = mis_lib.mis_greedy_update(
@@ -138,8 +142,8 @@ def _step_fn(metric: str, k: int, cfg: MatchConfig, unbatched: bool = False,
                 bm, cnt = mis_lib.mis_luby_update(
                     bm, cnt, emb, n_valid, tau, k, g.n)
             if capture:
-                return bm, cnt, found, ovf, peak, emb, n_valid
-            return bm, cnt, found, ovf, peak
+                return bm, cnt, found, ovf, peak, lanes, emb, n_valid
+            return bm, cnt, found, ovf, peak, lanes
 
         def step(g, plans, block_start, state, taus):
             bitmaps, counts = state
@@ -161,7 +165,7 @@ def _step_fn(metric: str, k: int, cfg: MatchConfig, unbatched: bool = False,
     elif metric in ("mni", "frac"):
 
         def step_one(g, plan, block_start, table, checks=None):
-            emb, n_valid, found, ovf, peak = match_block(
+            emb, n_valid, found, ovf, peak, lanes = match_block_lanes(
                 g, plan, block_start, cfg, checks)
             if metric == "mni":
                 table = metrics_lib.mni_update(table, emb, n_valid, k)
@@ -170,8 +174,8 @@ def _step_fn(metric: str, k: int, cfg: MatchConfig, unbatched: bool = False,
                 table = metrics_lib.frac_update(table, emb, n_valid, k)
                 value = metrics_lib.frac_value(table)
             if capture:
-                return table, value, found, ovf, peak, emb, n_valid
-            return table, value, found, ovf, peak
+                return table, value, found, ovf, peak, lanes, emb, n_valid
+            return table, value, found, ovf, peak, lanes
 
         def step(g, plans, block_start, state, taus):
             del taus  # MNI/frac need no device-side τ; the host owns early exit
@@ -426,7 +430,6 @@ def _mine_group(
     replay: Optional[List[dict]] = None,
     emb_sink=None,
     replan: bool = False,
-    counters: Optional[dict] = None,
 ) -> Tuple[List[Optional[PatternOutcome]], bool, int, np.ndarray, int]:
     """Run one same-k candidate group level-wise; returns
     (outcomes, timed_out, dispatches, block_peaks, replans).
@@ -449,9 +452,12 @@ def _mine_group(
     bit-identically; `flexis.mine` re-checks overflow against the full
     config cap, so a replan that shrinks too far only costs an escalation.
 
-    ``counters`` (optional dict) accumulates {"match_blocks",
-    "replay_blocks"} — the dispatch/block accounting the escalation-reuse
-    tests assert on.
+    Each block is a ``flexis.block`` span (`core/tracing.py`) holding its
+    ``dispatch``, ``pull``, ``account``, ``restack`` and ``hooks`` spans;
+    the group's set-up is a ``plan_build`` span.  The query's counters
+    gain ``match_blocks``/``replay_blocks``, ``host_pulls``, ``restacks``
+    and, on the XLA expansion, ``lanes_processed``/``lanes_useful`` of the
+    live rows.
 
     ``block_order`` is the static root-block schedule — a permutation of
     block ids from `planner.root_block_order` (None = vertex-id order), or
@@ -506,7 +512,6 @@ def _mine_group(
         # current bucket: stacked plans + state + map to group idx (-1 = pad)
         P_pad = _bucket_size(P0)
         bucket_map = np.concatenate([np.arange(P0), np.full(P_pad - P0, -1)])
-        state = _state_init(metric, P_pad, k, n)
         start_block = 0
         dispatches = 0
     else:
@@ -520,7 +525,6 @@ def _mine_group(
                        if resume.block_peaks is None
                        else resume.block_peaks.astype(np.int64).copy())
         bucket_map = np.asarray(resume.bucket_map, np.int64).copy()
-        state = jax.tree_util.tree_map(jnp.asarray, resume.state)
         start_block = int(resume.next_block)
         dispatches = int(resume.dispatches)
     replans = 0 if resume is None else int(getattr(resume, "replans", 0))
@@ -528,9 +532,12 @@ def _mine_group(
             and int(resume.cap) != cfg.cap:
         # continue at the geometry the killed process had replanned to
         cfg = dataclasses.replace(cfg, cap=int(resume.cap))
-    plans_cur = _gather_rows(stack_plans(plans),
-                             np.where(bucket_map >= 0, bucket_map, 0))
-    taus_dev = bucket_taus(bucket_map)
+    with tracing.span("plan_build", k=k, patterns=P0):
+        state = (_state_init(metric, bucket_map.size, k, n) if resume is None
+                 else jax.tree_util.tree_map(jnp.asarray, resume.state))
+        plans_cur = _gather_rows(stack_plans(plans),
+                                 np.where(bucket_map >= 0, bucket_map, 0))
+        taus_dev = bucket_taus(bucket_map)
 
     timed_out = False
     unfinished: set = set()
@@ -546,6 +553,8 @@ def _mine_group(
     # the P=1 bucket compiles without the vmap (fusion win, bit-identical);
     # re-resolved only when a shrink re-stack changes the bucket width
     capture = emb_sink is not None
+    # the Pallas kernel does not count its lanes
+    count_lanes = cfg.expansion == "xla"
     step = _step_fn(metric, k, cfg, unbatched=bucket_map.size == 1,
                     capture=capture)
     for b in range(start_block, n_blocks):
@@ -553,93 +562,111 @@ def _mine_group(
             timed_out = True
             unfinished = {int(i) for i in bucket_map[bucket_map >= 0]}
             break
-        if b in replay_at:
-            emb_np, nv_np, found_np, ovf_np, peak_np = _replay_arrays(
-                replay, bucket_map, b, cfg.cap, k)
-            state, values = rstep(
-                state, jnp.asarray(emb_np), jnp.asarray(nv_np), taus_dev)
-            values_np = np.asarray(values)
-            if counters is not None:
-                counters["replay_blocks"] = counters.get(
-                    "replay_blocks", 0) + 1
-        else:
-            out = step(
-                dev_g, plans_cur,
-                jnp.int32(int(block_order[b]) * cfg.root_block), state,
-                taus_dev)
-            state, values, blk_found, blk_ovf, blk_peak = out[:5]
-            values_np = np.asarray(values)
-            found_np = np.asarray(blk_found)
-            ovf_np = np.asarray(blk_ovf)
-            peak_np = np.asarray(blk_peak)
-            if capture:
-                emb_sink(b, np.asarray(out[5]), np.asarray(out[6]),
-                         found_np, ovf_np, peak_np, bucket_map)
-            if counters is not None:
-                counters["match_blocks"] = counters.get(
-                    "match_blocks", 0) + 1
-        dispatches += 1
+        with tracing.span("block", block=b, bucket=int(bucket_map.size)):
+            lanes_np = None
+            if b in replay_at:
+                emb_np, nv_np, found_np, ovf_np, peak_np = _replay_arrays(
+                    replay, bucket_map, b, cfg.cap, k)
+                with tracing.span("dispatch"):
+                    state, values = rstep(
+                        state, jnp.asarray(emb_np), jnp.asarray(nv_np),
+                        taus_dev)
+                with tracing.span("pull"):
+                    values_np = np.asarray(values)
+                tracing.count("host_pulls")
+                tracing.count("replay_blocks")
+            else:
+                with tracing.span("dispatch"):
+                    out = step(
+                        dev_g, plans_cur,
+                        jnp.int32(int(block_order[b]) * cfg.root_block),
+                        state, taus_dev)
+                state, values, blk_found, blk_ovf, blk_peak = out[:5]
+                with tracing.span("pull"):
+                    values_np = np.asarray(values)
+                    found_np = np.asarray(blk_found)
+                    ovf_np = np.asarray(blk_ovf)
+                    peak_np = np.asarray(blk_peak)
+                    if count_lanes:
+                        lanes_np = np.asarray(out[5])
+                tracing.count("host_pulls", 5 if count_lanes else 4)
+                tracing.count("match_blocks")
+                if capture:
+                    with tracing.span("hooks"):
+                        emb_sink(b, np.asarray(out[6]), np.asarray(out[7]),
+                                 found_np, ovf_np, peak_np, bucket_map)
+                    tracing.count("host_pulls", 2)
+            dispatches += 1
 
-        live = bucket_map >= 0
-        gi = bucket_map[live]
-        found[gi] += found_np[live].astype(np.int64)
-        ovf[gi] |= ovf_np[live]
-        blocks_run[gi] += 1
-        max_count[gi] = np.maximum(max_count[gi],
-                                   peak_np[live].astype(np.int64))
-        bid = int(block_order[b])
-        block_peaks[bid] = max(block_peaks[bid],
-                               int(peak_np[live].max(initial=0)))
-        if metric == "frac":
-            supports[gi] = np.floor(values_np[live].astype(np.float64)).astype(np.int64)
-        else:
-            supports[gi] = values_np[live].astype(np.int64)
+            with tracing.span("account"):
+                live = bucket_map >= 0
+                gi = bucket_map[live]
+                if lanes_np is not None:
+                    walked, useful = lanes_np[live].sum(axis=0).tolist()
+                    tracing.count("lanes_processed", walked)
+                    tracing.count("lanes_useful", useful)
+                found[gi] += found_np[live].astype(np.int64)
+                ovf[gi] |= ovf_np[live]
+                blocks_run[gi] += 1
+                max_count[gi] = np.maximum(max_count[gi],
+                                           peak_np[live].astype(np.int64))
+                bid = int(block_order[b])
+                block_peaks[bid] = max(block_peaks[bid],
+                                       int(peak_np[live].max(initial=0)))
+                if metric == "frac":
+                    supports[gi] = np.floor(values_np[live].astype(np.float64)).astype(np.int64)
+                else:
+                    supports[gi] = values_np[live].astype(np.int64)
+                if early_exit:
+                    still = gi[supports[gi] < taus_np[gi]]
 
-        if early_exit:
-            still = gi[supports[gi] < taus_np[gi]]
-            if still.size == 0:
-                break
-            if still.size <= bucket_map.size // 2 and b + 1 < n_blocks:
-                # shrink: re-stack survivors into the next power-of-two bucket
-                pos_of = {g_idx: i for i, g_idx in enumerate(bucket_map)}
-                pos = np.array([pos_of[g_idx] for g_idx in still])
-                pad = _bucket_size(still.size) - still.size
-                sel = np.concatenate([pos, np.full(pad, pos[0])]).astype(np.int64)
-                plans_cur = _gather_rows(plans_cur, sel)
-                state = _gather_rows(state, sel)
-                bucket_map = np.concatenate([still, np.full(pad, -1)])
-                taus_dev = bucket_taus(bucket_map)
-                if replan and not ovf[still].any():
-                    # within-level replanning: the survivors' measured peak
-                    # may fit a much smaller frontier cap — re-derive it
-                    # with the planner's headroom/floor rails (never once a
-                    # live pattern has overflowed: truncation is the only
-                    # cap-dependent behaviour and it must stay flagged)
-                    from .planner import CAP_FLOOR, CAP_HEADROOM
-                    live_peak = int(max_count[still].max())
-                    if live_peak > 0:
-                        new_cap = min(cfg.cap,
-                                      max(_bucket_size(CAP_HEADROOM
-                                                       * live_peak),
-                                          CAP_FLOOR))
-                        if new_cap < cfg.cap:
-                            cfg = dataclasses.replace(cfg, cap=new_cap)
-                            replans += 1
-                step = _step_fn(metric, k, cfg,
-                                unbatched=bucket_map.size == 1,
-                                capture=capture)
-            elif still.size < gi.size:
-                # same bucket; just stop accounting for the finished patterns
-                bucket_map = np.where(np.isin(bucket_map, still), bucket_map, -1)
+            if early_exit:
+                if still.size == 0:
+                    break
+                if still.size <= bucket_map.size // 2 and b + 1 < n_blocks:
+                    with tracing.span("restack", survivors=int(still.size)):
+                        # shrink: re-stack survivors into the next power-of-two bucket
+                        pos_of = {g_idx: i for i, g_idx in enumerate(bucket_map)}
+                        pos = np.array([pos_of[g_idx] for g_idx in still])
+                        pad = _bucket_size(still.size) - still.size
+                        sel = np.concatenate([pos, np.full(pad, pos[0])]).astype(np.int64)
+                        plans_cur = _gather_rows(plans_cur, sel)
+                        state = _gather_rows(state, sel)
+                        bucket_map = np.concatenate([still, np.full(pad, -1)])
+                        taus_dev = bucket_taus(bucket_map)
+                        if replan and not ovf[still].any():
+                            # within-level replanning: the survivors' measured peak
+                            # may fit a much smaller frontier cap — re-derive it
+                            # with the planner's headroom/floor rails (never once a
+                            # live pattern has overflowed: truncation is the only
+                            # cap-dependent behaviour and it must stay flagged)
+                            from .planner import CAP_FLOOR, CAP_HEADROOM
+                            live_peak = int(max_count[still].max())
+                            if live_peak > 0:
+                                new_cap = min(cfg.cap,
+                                              max(_bucket_size(CAP_HEADROOM
+                                                               * live_peak),
+                                                  CAP_FLOOR))
+                                if new_cap < cfg.cap:
+                                    cfg = dataclasses.replace(cfg, cap=new_cap)
+                                    replans += 1
+                        step = _step_fn(metric, k, cfg,
+                                        unbatched=bucket_map.size == 1,
+                                        capture=capture)
+                    tracing.count("restacks")
+                elif still.size < gi.size:
+                    # same bucket; just stop accounting for the finished patterns
+                    bucket_map = np.where(np.isin(bucket_map, still), bucket_map, -1)
 
-        if on_block is not None and b + 1 < n_blocks:
-            on_block(GroupState(
-                next_block=b + 1, bucket_map=bucket_map.copy(), state=state,
-                supports=supports.copy(), found=found.copy(),
-                overflowed=ovf.copy(), blocks_run=blocks_run.copy(),
-                dispatches=dispatches, max_count=max_count.copy(),
-                block_peaks=block_peaks.copy(), cap=int(cfg.cap),
-                replans=replans))
+            if on_block is not None and b + 1 < n_blocks:
+                with tracing.span("hooks"):
+                    on_block(GroupState(
+                        next_block=b + 1, bucket_map=bucket_map.copy(), state=state,
+                        supports=supports.copy(), found=found.copy(),
+                        overflowed=ovf.copy(), blocks_run=blocks_run.copy(),
+                        dispatches=dispatches, max_count=max_count.copy(),
+                        block_peaks=block_peaks.copy(), cap=int(cfg.cap),
+                        replans=replans))
 
     outcomes: List[Optional[PatternOutcome]] = [
         None if i in unfinished else PatternOutcome(
@@ -670,14 +697,12 @@ def evaluate_level_batched(
     block_order: Optional[np.ndarray] = None,
     replay: Optional[List[dict]] = None,
     replan: bool = False,
-    counters: Optional[dict] = None,
 ) -> Tuple[List[Optional[PatternOutcome]], bool, LevelTelemetry]:
     """Evaluate a whole candidate level with the batched data plane.
 
-    ``replay``/``replan``/``counters`` thread through to `_mine_group`
-    (escalation reuse, within-level replanning, block accounting — see its
-    docstring); ``replay`` aligns with ``patterns`` and is sliced per
-    group.
+    ``replay``/``replan`` thread through to `_mine_group` (escalation
+    reuse, within-level replanning — see its docstring); ``replay`` aligns
+    with ``patterns`` and is sliced per group.
 
     Args:
       host_g/dev_g: the data graph and its device mirror.
@@ -739,7 +764,8 @@ def evaluate_level_batched(
             for i in idxs:
                 outcomes[i] = prefilled[i]
             continue
-        plans = [make_plan(patterns[i], host_g) for i in idxs]
+        with tracing.span("plan_build", k=k, patterns=len(idxs)):
+            plans = [make_plan(patterns[i], host_g) for i in idxs]
         group_taus = [taus[i] for i in idxs]
         resume = hooks.group_resume(k, lo) if hooks is not None else None
         on_block = (functools.partial(hooks.on_group_state, k, lo)
@@ -750,16 +776,17 @@ def evaluate_level_batched(
                 dev_g, plans, group_taus, metric, cfg,
                 complete=complete, n=host_g.n, deadline=deadline,
                 resume=resume, on_block=on_block, block_order=block_order,
-                replay=group_replay, replan=replan, counters=counters)
+                replay=group_replay, replan=replan)
         telemetry.dispatches += dispatches
         telemetry.replans += group_replans
         peaks = np.maximum(peaks, group_peaks)
         for i, out in zip(idxs, got):
             outcomes[i] = out
         if hooks is not None and not group_timed_out:
-            hooks.on_group_done(k, lo, idxs, got, dispatches,
-                                block_peaks=[int(x) for x in group_peaks],
-                                replans=group_replans)
+            with tracing.span("hooks"):
+                hooks.on_group_done(k, lo, idxs, got, dispatches,
+                                    block_peaks=[int(x) for x in group_peaks],
+                                    replans=group_replans)
         if group_timed_out:
             timed_out = True
             break

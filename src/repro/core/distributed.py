@@ -51,6 +51,7 @@ from .matcher import (MatchConfig, batch_checks, match_block,
                       transient_match_bytes)
 from . import mis as mis_lib
 from . import batched as batched_lib
+from . import tracing
 
 __all__ = ["mining_mesh", "sharded_mis_step", "distributed_support",
            "sharded_batched_mis_step", "distributed_batched_supports",
@@ -280,8 +281,9 @@ def iter_batched_supports(
     ndev = int(np.prod(list(mesh.shape.values())))
     cfg = match_cfg or MatchConfig.for_graph(host_g)
     dev_g = _replicated(DeviceGraph.from_host(host_g), mesh)
-    plans = _replicated(
-        stack_plans([make_plan(p, host_g) for p in patterns]), mesh)
+    with tracing.span("plan_build", k=k, patterns=len(patterns)):
+        plans = _replicated(
+            stack_plans([make_plan(p, host_g) for p in patterns]), mesh)
     n = host_g.n
     P_ = len(patterns)
     taus_np = np.asarray(taus, np.int64)
@@ -312,42 +314,52 @@ def iter_batched_supports(
         block_order = np.arange(n_blocks, dtype=np.int64)
     assert block_order.shape[0] == n_blocks
     while next_block < n_blocks:
-        counts_np = np.asarray(counts, np.int64)
-        if not complete and bool((counts_np >= taus_np).all()):
-            return
-        # per-pattern freeze at super-block granularity: a pattern that
-        # already reached τ stops accumulating telemetry (its device state
-        # is frozen anyway by the cnt < τ guard in the Luby rounds)
-        active = np.ones(P_, bool) if complete else counts_np < taus_np
-        stop = min(next_block + bps, n_blocks)
-        sb_found = np.zeros(P_, np.int64)
-        sb_ovf = np.zeros(P_, bool)
-        sb_peak = np.zeros(P_, np.int64)
-        for lo in range(next_block, stop, ndev):
-            # pad tail dispatches with empty blocks (start ≥ n matches no
-            # roots) so a super-block never leaks into the next one
-            pos = lo + np.arange(ndev)
-            ids = block_order[np.minimum(pos, n_blocks - 1)]
-            starts = jnp.asarray(
-                np.where(pos < stop, ids * cfg.root_block, n), jnp.int32)
-            bitmaps, counts, d_found, d_ovf, d_peak = sharded_batched_mis_step(
-                dev_g, plans, starts, bitmaps, counts, tau_dev,
-                cfg=cfg, k=k, n=n, axis=axis, mesh=mesh)
-            sb_found += np.asarray(d_found, np.int64)
-            sb_ovf |= np.asarray(d_ovf, bool)
-            sb_peak = np.maximum(sb_peak, np.asarray(d_peak, np.int64))
-            dispatches += 1
-        found[active] += sb_found[active]
-        ovf[active] |= sb_ovf[active]
-        blocks_run[active] += stop - next_block
-        max_count[active] = np.maximum(max_count[active], sb_peak[active])
-        next_block = stop
-        super_blocks += 1
-        state = SuperBlockState(
-            next_block=next_block, bitmaps=bitmaps, counts=counts,
-            found=found.copy(), overflowed=ovf.copy(),
-            blocks_run=blocks_run.copy(), super_blocks_run=super_blocks,
-            dispatches=dispatches, max_count=max_count.copy())
+        # a super-block is one flexis.block span, closed before the yield
+        # so that no span stays open while the consumer runs
+        with tracing.span("block", block=next_block, bucket=P_):
+            with tracing.span("pull"):
+                counts_np = np.asarray(counts, np.int64)
+            tracing.count("host_pulls")
+            if not complete and bool((counts_np >= taus_np).all()):
+                return
+            # per-pattern freeze at super-block granularity: a pattern that
+            # already reached τ stops accumulating telemetry (its device state
+            # is frozen anyway by the cnt < τ guard in the Luby rounds)
+            active = np.ones(P_, bool) if complete else counts_np < taus_np
+            stop = min(next_block + bps, n_blocks)
+            sb_found = np.zeros(P_, np.int64)
+            sb_ovf = np.zeros(P_, bool)
+            sb_peak = np.zeros(P_, np.int64)
+            for lo in range(next_block, stop, ndev):
+                # pad tail dispatches with empty blocks (start ≥ n matches no
+                # roots) so a super-block never leaks into the next one
+                pos = lo + np.arange(ndev)
+                ids = block_order[np.minimum(pos, n_blocks - 1)]
+                starts = jnp.asarray(
+                    np.where(pos < stop, ids * cfg.root_block, n), jnp.int32)
+                with tracing.span("dispatch"):
+                    bitmaps, counts, d_found, d_ovf, d_peak = sharded_batched_mis_step(
+                        dev_g, plans, starts, bitmaps, counts, tau_dev,
+                        cfg=cfg, k=k, n=n, axis=axis, mesh=mesh)
+                with tracing.span("pull"):
+                    sb_found += np.asarray(d_found, np.int64)
+                    sb_ovf |= np.asarray(d_ovf, bool)
+                    sb_peak = np.maximum(sb_peak, np.asarray(d_peak, np.int64))
+                tracing.count("host_pulls", 3)
+                tracing.count("match_blocks")
+                dispatches += 1
+            with tracing.span("account"):
+                found[active] += sb_found[active]
+                ovf[active] |= sb_ovf[active]
+                blocks_run[active] += stop - next_block
+                max_count[active] = np.maximum(max_count[active], sb_peak[active])
+                next_block = stop
+                super_blocks += 1
+                state = SuperBlockState(
+                    next_block=next_block, bitmaps=bitmaps, counts=counts,
+                    found=found.copy(), overflowed=ovf.copy(),
+                    blocks_run=blocks_run.copy(), super_blocks_run=super_blocks,
+                    dispatches=dispatches, max_count=max_count.copy())
         yield state
 
 
@@ -465,7 +477,8 @@ def evaluate_level_distributed(
             except StopIteration:
                 break
             if hooks is not None:
-                hooks.on_group_state(k, lo, last)
+                with tracing.span("hooks"):
+                    hooks.on_group_state(k, lo, last)
         telemetry.dispatches += int(last.dispatches)
         if group_timed_out:
             timed_out = True
@@ -487,7 +500,8 @@ def evaluate_level_distributed(
         for i, out in zip(idxs, got):
             outcomes[i] = out
         if hooks is not None:
-            hooks.on_group_done(k, lo, idxs, got, int(last.dispatches))
+            with tracing.span("hooks"):
+                hooks.on_group_done(k, lo, idxs, got, int(last.dispatches))
     assert timed_out or all(o is not None for o in outcomes)
     for o in outcomes:
         if o is not None:

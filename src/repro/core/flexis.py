@@ -30,6 +30,7 @@ from . import batched as batched_lib
 from . import sampled as sampled_lib
 from . import mis as mis_lib
 from . import metrics as metrics_lib
+from . import tracing
 
 __all__ = ["MiningConfig", "MiningLoopState", "PatternStats", "MiningResult",
            "tau_threshold", "mine", "evaluate_pattern", "initial_candidates"]
@@ -157,8 +158,8 @@ class MiningResult:
     # occupancy across the level's patterns and whether any hit the cap —
     # the planner's geometry inputs), "plan" (the planner's recorded
     # decision dict, present under execution="auto") and "wall_s" (wall
-    # clock spent on the level *in this process*; excluded from resume
-    # bit-identity comparisons)
+    # clock spent on the level *in this process*, the duration of its
+    # ``flexis.level`` span; excluded from resume bit-identity comparisons)
     per_level: Dict[int, Dict[str, Any]]
     stats: List[PatternStats]
     elapsed_s: float
@@ -172,6 +173,10 @@ class MiningResult:
     # "platform:device_kind" of the device that held the data graph, where
     # the single-device planes ran every step
     device: str = ""
+    # the query's spans and counters (`core/tracing.py`); excluded from
+    # resume bit-identity comparisons, like ``health``
+    trace: tracing.QueryTrace = dataclasses.field(
+        default_factory=tracing.QueryTrace)
 
 
 @dataclasses.dataclass
@@ -363,15 +368,32 @@ def mine(g: DataGraph, cfg: MiningConfig, *, hooks=None,
 
     A run resumed from any snapshot produces the same `MiningResult` as the
     uninterrupted run, except wall-clock fields (``elapsed_s``, per-level
-    ``wall_s``).
+    ``wall_s``) and ``trace``.
+
+    The call is one query of `core/tracing.py`: its spans (``flexis.mine``
+    at the root) and counters come back as ``MiningResult.trace``, and
+    ``elapsed_s`` and each level's ``wall_s`` are span durations.
     """
+    with tracing.query() as trace:
+        with tracing.span("mine") as root:
+            res = _mine(g, cfg, hooks, health, root)
+    res.elapsed_s += root.seconds
+    res.trace = trace
+    return res
+
+
+def _mine(g: DataGraph, cfg: MiningConfig, hooks, health: Optional[RunHealth],
+          root: tracing.Span) -> MiningResult:
+    """`mine` inside its root span ``root``; the result's ``elapsed_s``
+    holds the time of earlier processes only (a resumed session's)."""
     t0 = time.monotonic()
     if health is None:
         health = RunHealth()
-    dev_g = DeviceGraph.from_host(g)
-    graph_bytes = g.nbytes()
+    with tracing.span("setup"):
+        dev_g = DeviceGraph.from_host(g)
+        graph_bytes = g.nbytes()
+        resume = hooks.loop_resume() if hooks is not None else None
 
-    resume = hooks.loop_resume() if hooks is not None else None
     if resume is None:
         frequent: List[Tuple[Pattern, int]] = []
         all_stats: List[PatternStats] = []
@@ -379,7 +401,8 @@ def mine(g: DataGraph, cfg: MiningConfig, *, hooks=None,
         searched = 0
         peak_bytes = graph_bytes
         timed_out = False
-        cp = initial_candidates(g)
+        with tracing.span("cand_build"):
+            cp = initial_candidates(g)
         level = 0
         elapsed0 = 0.0
     else:
@@ -402,20 +425,21 @@ def mine(g: DataGraph, cfg: MiningConfig, *, hooks=None,
     # planner's static root-block schedule (cfg.root_order)
     import jax
 
-    device = next(iter(dev_g.labels.devices()))
-    cost = planner_lib.load_calibration(device=device)
-    n_devices = jax.local_device_count()
-    if hooks is not None and hasattr(hooks, "pin_calibration"):
-        # sessions pin the planner inputs in the snapshot so a resume on a
-        # machine with a different calibration file — or a different
-        # device count — replans identically (CostModel.from_dict ignores
-        # the extra n_devices key)
-        pinned = hooks.pin_calibration(
-            {**cost.to_dict(), "n_devices": n_devices})
-        cost = CostModel.from_dict(pinned)
-        n_devices = int(pinned.get("n_devices", n_devices))
-    planner = ExecutionPlanner(g, cfg, cost_model=cost,
-                               n_devices=n_devices, platform=device.platform)
+    with tracing.span("setup"):
+        device = next(iter(dev_g.labels.devices()))
+        cost = planner_lib.load_calibration(device=device)
+        n_devices = jax.local_device_count()
+        if hooks is not None and hasattr(hooks, "pin_calibration"):
+            # sessions pin the planner inputs in the snapshot so a resume on a
+            # machine with a different calibration file — or a different
+            # device count — replans identically (CostModel.from_dict ignores
+            # the extra n_devices key)
+            pinned = hooks.pin_calibration(
+                {**cost.to_dict(), "n_devices": n_devices})
+            cost = CostModel.from_dict(pinned)
+            n_devices = int(pinned.get("n_devices", n_devices))
+        planner = ExecutionPlanner(g, cfg, cost_model=cost,
+                                   n_devices=n_devices, platform=device.platform)
     block_order = planner.block_order
     deadline = (None if cfg.time_limit_s is None
                 else t0 + max(cfg.time_limit_s - elapsed0, 0.0))
@@ -425,259 +449,265 @@ def mine(g: DataGraph, cfg: MiningConfig, *, hooks=None,
             level=level, cp=list(next_cp), frequent=list(frequent),
             stats=list(all_stats), per_level=dict(per_level),
             searched=searched, peak_bytes=peak_bytes,
-            elapsed_s=elapsed0 + (time.monotonic() - t0),
+            elapsed_s=elapsed0 + (tracing.clock_ns() - root.start_ns) / 1e9,
             timed_out=timed_out)
 
     while cp:
         level += 1
-        level_t0 = time.monotonic()
-        level_hooks = hooks.level_hooks(level) if hooks is not None else None
-        level_frequent: List[Pattern] = []
-        lvl_searched = 0
-        lvl_pruned = 0
-        lvl_dispatches = 0
-        lvl_max_count = 0
-        lvl_overflowed = False
-        eval_pats: List[Pattern] = []
-        eval_taus: List[int] = []
-        for pat in cp:
-            tau = (
-                tau_threshold(cfg.sigma, cfg.lam, pat.k) if mis_mode else cfg.sigma
-            )
-            # paper §3.1.2 vertex bound: a frequent k-pattern needs k·τ
-            # distinct data vertices under the independence property
-            if mis_mode and pat.k * tau > g.n:
-                lvl_pruned += 1
-                continue
-            eval_pats.append(pat)
-            eval_taus.append(tau)
-
-        # plan the level: a mid-level resume replays the recorded decision
-        # (calibration drift between processes must not move the plan);
-        # otherwise the planner decides from the previous level's telemetry
-        plan: Optional[LevelPlan] = None
-        if level_hooks is not None:
-            resume_plan = getattr(level_hooks, "resume_plan", None)
-            d = resume_plan() if resume_plan is not None else None
-            if d is not None:
-                plan = LevelPlan.from_dict(d, cfg.match)
-        if plan is None:
-            plan = planner.plan_level(level, eval_pats, eval_taus,
-                                      prev=per_level.get(level - 1))
-        if level_hooks is not None and cfg.execution in ("auto", "sampled"):
-            # sampled plans are recorded too: the level's block draw lives
-            # in plan.sample and a resume must replay it, not re-draw it
-            record_plan = getattr(level_hooks, "record_plan", None)
-            if record_plan is not None:
-                record_plan(plan.to_dict())
-        plane = plan.plane if cfg.metric != "mis_exact" else "sequential"
-
-        tel = None
-        if plane in ("batched", "distributed", "sampled") and eval_pats:
-            if plane == "sampled":
-                outcomes, lvl_timed_out, tel = sampled_lib.evaluate_level_sampled(
-                    g, dev_g, eval_pats, eval_taus, cfg.metric, plan.match,
-                    sample=plan.sample, confidence=cfg.confidence,
-                    escalate=cfg.escalate, complete=cfg.complete,
-                    deadline=deadline, max_batch=plan.max_batch,
-                    hooks=level_hooks, block_order=block_order,
-                    sample_rounds=cfg.sample_rounds)
-            elif plane == "distributed":
-                from . import distributed as distributed_lib
-
-                try:
-                    outcomes, lvl_timed_out, tel = distributed_lib.evaluate_level_distributed(
-                        g, eval_pats, eval_taus, plan.match,
-                        complete=cfg.complete, deadline=deadline,
-                        max_batch=plan.max_batch,
-                        blocks_per_super=cfg.blocks_per_super,
-                        hooks=level_hooks, block_order=block_order)
-                except Exception as e:
-                    # graceful degradation: a failed mesh/collective — or a
-                    # mesh that can no longer satisfy the recorded plan —
-                    # must not fail the query.  Re-run the level on the
-                    # batched plane: supports are bit-identical by the
-                    # plane-equivalence contract, and completed groups the
-                    # failed attempt recorded are replayed (only the
-                    # in-flight super-block cursor is dropped — it is the
-                    # wrong plane's resume unit).  `InjectedCrash` and
-                    # `PreemptedError` are BaseExceptions and fly past this
-                    # on purpose: a kill is not a mesh failure.
-                    health.record(
-                        "plane_fallback",
-                        f"distributed level failed "
-                        f"({type(e).__name__}: {e}); degrading to batched",
-                        level=level)
-                    if level_hooks is not None:
-                        drop = getattr(level_hooks, "drop_inflight", None)
-                        if drop is not None:
-                            drop()
-                    plan = dataclasses.replace(plan, plane="batched")
-                    if level_hooks is not None:
-                        record_plan = getattr(level_hooks, "record_plan",
-                                              None)
-                        if record_plan is not None:
-                            # a mid-level snapshot after this point must
-                            # resume on the batched plane, whatever the
-                            # original plan said
-                            record_plan(plan.to_dict())
-                    plane = "batched"
-                    outcomes, lvl_timed_out, tel = batched_lib.evaluate_level_batched(
-                        g, dev_g, eval_pats, eval_taus, cfg.metric,
-                        plan.match, complete=cfg.complete, deadline=deadline,
-                        max_batch=plan.max_batch, hooks=level_hooks,
-                        block_order=block_order)
-            else:
-                # within-level replanning is an auto-plane behaviour: the
-                # forced batched plane is the bit-identity oracle and must
-                # keep the config geometry verbatim
-                outcomes, lvl_timed_out, tel = batched_lib.evaluate_level_batched(
-                    g, dev_g, eval_pats, eval_taus, cfg.metric, plan.match,
-                    complete=cfg.complete, deadline=deadline,
-                    max_batch=plan.max_batch, hooks=level_hooks,
-                    block_order=block_order,
-                    replan=cfg.execution == "auto")
-            timed_out |= lvl_timed_out
-            lvl_dispatches += tel.dispatches
-            lvl_max_count = max(lvl_max_count, tel.max_count)
-            lvl_overflowed |= tel.overflowed
-            peak_bytes = max(peak_bytes, graph_bytes + tel.state_bytes)
-            # graceful degradation, exactness half: the planner's
-            # right-sized cap guarantees headroom only over the *previous*
-            # level's peak, so a level can still overflow it.  Truncation
-            # is the only cap-dependent behaviour, so re-running just the
-            # overflowed patterns at the config's base geometry restores
-            # forced-plane equality (a non-overflowed pattern's history is
-            # cap-invariant, hence identical to the base-cap run already).
-            # Pure function of the recorded outcomes → a resumed run
-            # escalates identically.
-            esc = [i for i, o in enumerate(outcomes)
-                   if o is not None and o.overflowed]
-            # a within-level replan can shrink the cap below the plan's,
-            # so replans make the level escalation-eligible even when the
-            # plan kept the base geometry
-            replanned = tel is not None and getattr(tel, "replans", 0) > 0
-            if esc and not timed_out \
-                    and (plan.match.cap < cfg.match.cap or replanned):
-                re_out, re_to, re_tel = batched_lib.evaluate_level_batched(
-                    g, dev_g, [eval_pats[i] for i in esc],
-                    [eval_taus[i] for i in esc], cfg.metric, cfg.match,
-                    complete=cfg.complete, deadline=deadline,
-                    max_batch=plan.max_batch, block_order=block_order)
-                timed_out |= re_to
-                lvl_dispatches += re_tel.dispatches
-                peak_bytes = max(peak_bytes, graph_bytes + re_tel.state_bytes)
-                outcomes = list(outcomes)
-                done = 0
-                for i, o in zip(esc, re_out):
-                    if o is not None:
-                        outcomes[i] = o
-                        done += 1
-                # occupancy telemetry must describe the *final* outcomes
-                # (forced-plane equality covers max_count/overflowed too,
-                # and the next level's plan is derived from these)
-                lvl_max_count = max((o.max_count for o in outcomes
-                                     if o is not None), default=0)
-                lvl_overflowed = any(o.overflowed for o in outcomes
-                                     if o is not None)
-                health.record(
-                    "overflow_escalation",
-                    f"{done}/{len(esc)} patterns overflowed derived cap "
-                    f"{plan.match.cap}; re-run at base cap {cfg.match.cap}",
-                    level=level)
-            for pat, tau, out in zip(eval_pats, eval_taus, outcomes):
-                if out is None:  # level timed out before this group ran
-                    continue
-                st = PatternStats(
-                    pattern=pat,
-                    support=out.support,
-                    tau=tau,
-                    frequent=out.frequent,
-                    embeddings_found=out.embeddings_found,
-                    overflowed=out.overflowed,
-                    blocks_run=out.blocks_run,
-                    max_count=out.max_count,
-                    estimated=getattr(out, "estimated", False),
+        with tracing.span("level", level=level, candidates=len(cp)) as lvl:
+            level_hooks = hooks.level_hooks(level) if hooks is not None else None
+            level_frequent: List[Pattern] = []
+            lvl_searched = 0
+            lvl_pruned = 0
+            lvl_dispatches = 0
+            lvl_max_count = 0
+            lvl_overflowed = False
+            eval_pats: List[Pattern] = []
+            eval_taus: List[int] = []
+            for pat in cp:
+                tau = (
+                    tau_threshold(cfg.sigma, cfg.lam, pat.k) if mis_mode else cfg.sigma
                 )
-                searched += 1
-                lvl_searched += 1
-                all_stats.append(st)
-                if st.frequent:
-                    frequent.append((pat, st.support))
-                    level_frequent.append(pat)
-        else:
-            seq_stats: List[PatternStats] = []
-            for pat, tau in zip(eval_pats, eval_taus):
-                if deadline is not None and time.monotonic() > deadline:
-                    timed_out = True
-                    break
-                st = evaluate_pattern(g, dev_g, pat, tau, cfg,
-                                      match_cfg=plan.match,
-                                      block_order=block_order)
-                lvl_dispatches += st.dispatches
-                seq_stats.append(st)
-                peak_bytes = max(
-                    peak_bytes,
-                    graph_bytes + _device_bytes(plan.match, cfg.metric,
-                                                pat.k, g.n))
-            # same overflow-escalation pass as the plane branch (the
-            # sequential plane carries an auto-derived cap too — mis_exact
-            # under execution="auto" in particular always lands here)
-            if plan.match.cap < cfg.match.cap and not timed_out:
-                n_esc = 0
-                for j, st in enumerate(seq_stats):
-                    if not st.overflowed:
+                # paper §3.1.2 vertex bound: a frequent k-pattern needs k·τ
+                # distinct data vertices under the independence property
+                if mis_mode and pat.k * tau > g.n:
+                    lvl_pruned += 1
+                    continue
+                eval_pats.append(pat)
+                eval_taus.append(tau)
+
+            # plan the level: a mid-level resume replays the recorded decision
+            # (calibration drift between processes must not move the plan);
+            # otherwise the planner decides from the previous level's telemetry
+            plan: Optional[LevelPlan] = None
+            if level_hooks is not None:
+                resume_plan = getattr(level_hooks, "resume_plan", None)
+                d = resume_plan() if resume_plan is not None else None
+                if d is not None:
+                    plan = LevelPlan.from_dict(d, cfg.match)
+            if plan is None:
+                with tracing.span("plan"):
+                    plan = planner.plan_level(level, eval_pats, eval_taus,
+                                              prev=per_level.get(level - 1))
+            if level_hooks is not None and cfg.execution in ("auto", "sampled"):
+                # sampled plans are recorded too: the level's block draw lives
+                # in plan.sample and a resume must replay it, not re-draw it
+                record_plan = getattr(level_hooks, "record_plan", None)
+                if record_plan is not None:
+                    record_plan(plan.to_dict())
+            plane = plan.plane if cfg.metric != "mis_exact" else "sequential"
+
+            tel = None
+            if plane in ("batched", "distributed", "sampled") and eval_pats:
+                if plane == "sampled":
+                    outcomes, lvl_timed_out, tel = sampled_lib.evaluate_level_sampled(
+                        g, dev_g, eval_pats, eval_taus, cfg.metric, plan.match,
+                        sample=plan.sample, confidence=cfg.confidence,
+                        escalate=cfg.escalate, complete=cfg.complete,
+                        deadline=deadline, max_batch=plan.max_batch,
+                        hooks=level_hooks, block_order=block_order,
+                        sample_rounds=cfg.sample_rounds)
+                elif plane == "distributed":
+                    from . import distributed as distributed_lib
+
+                    try:
+                        outcomes, lvl_timed_out, tel = distributed_lib.evaluate_level_distributed(
+                            g, eval_pats, eval_taus, plan.match,
+                            complete=cfg.complete, deadline=deadline,
+                            max_batch=plan.max_batch,
+                            blocks_per_super=cfg.blocks_per_super,
+                            hooks=level_hooks, block_order=block_order)
+                    except Exception as e:
+                        # graceful degradation: a failed mesh/collective — or a
+                        # mesh that can no longer satisfy the recorded plan —
+                        # must not fail the query.  Re-run the level on the
+                        # batched plane: supports are bit-identical by the
+                        # plane-equivalence contract, and completed groups the
+                        # failed attempt recorded are replayed (only the
+                        # in-flight super-block cursor is dropped — it is the
+                        # wrong plane's resume unit).  `InjectedCrash` and
+                        # `PreemptedError` are BaseExceptions and fly past this
+                        # on purpose: a kill is not a mesh failure.
+                        health.record(
+                            "plane_fallback",
+                            f"distributed level failed "
+                            f"({type(e).__name__}: {e}); degrading to batched",
+                            level=level)
+                        if level_hooks is not None:
+                            drop = getattr(level_hooks, "drop_inflight", None)
+                            if drop is not None:
+                                drop()
+                        plan = dataclasses.replace(plan, plane="batched")
+                        if level_hooks is not None:
+                            record_plan = getattr(level_hooks, "record_plan",
+                                                  None)
+                            if record_plan is not None:
+                                # a mid-level snapshot after this point must
+                                # resume on the batched plane, whatever the
+                                # original plan said
+                                record_plan(plan.to_dict())
+                        plane = "batched"
+                        outcomes, lvl_timed_out, tel = batched_lib.evaluate_level_batched(
+                            g, dev_g, eval_pats, eval_taus, cfg.metric,
+                            plan.match, complete=cfg.complete, deadline=deadline,
+                            max_batch=plan.max_batch, hooks=level_hooks,
+                            block_order=block_order)
+                else:
+                    # within-level replanning is an auto-plane behaviour: the
+                    # forced batched plane is the bit-identity oracle and must
+                    # keep the config geometry verbatim
+                    outcomes, lvl_timed_out, tel = batched_lib.evaluate_level_batched(
+                        g, dev_g, eval_pats, eval_taus, cfg.metric, plan.match,
+                        complete=cfg.complete, deadline=deadline,
+                        max_batch=plan.max_batch, hooks=level_hooks,
+                        block_order=block_order,
+                        replan=cfg.execution == "auto")
+                timed_out |= lvl_timed_out
+                lvl_dispatches += tel.dispatches
+                lvl_max_count = max(lvl_max_count, tel.max_count)
+                lvl_overflowed |= tel.overflowed
+                peak_bytes = max(peak_bytes, graph_bytes + tel.state_bytes)
+                # graceful degradation, exactness half: the planner's
+                # right-sized cap guarantees headroom only over the *previous*
+                # level's peak, so a level can still overflow it.  Truncation
+                # is the only cap-dependent behaviour, so re-running just the
+                # overflowed patterns at the config's base geometry restores
+                # forced-plane equality (a non-overflowed pattern's history is
+                # cap-invariant, hence identical to the base-cap run already).
+                # Pure function of the recorded outcomes → a resumed run
+                # escalates identically.
+                esc = [i for i, o in enumerate(outcomes)
+                       if o is not None and o.overflowed]
+                # a within-level replan can shrink the cap below the plan's,
+                # so replans make the level escalation-eligible even when the
+                # plan kept the base geometry
+                replanned = tel is not None and getattr(tel, "replans", 0) > 0
+                if esc and not timed_out \
+                        and (plan.match.cap < cfg.match.cap or replanned):
+                    tracing.count("escalated", len(esc))
+                    with tracing.span("escalate", patterns=len(esc)):
+                        re_out, re_to, re_tel = batched_lib.evaluate_level_batched(
+                            g, dev_g, [eval_pats[i] for i in esc],
+                            [eval_taus[i] for i in esc], cfg.metric, cfg.match,
+                            complete=cfg.complete, deadline=deadline,
+                            max_batch=plan.max_batch, block_order=block_order)
+                    timed_out |= re_to
+                    lvl_dispatches += re_tel.dispatches
+                    peak_bytes = max(peak_bytes, graph_bytes + re_tel.state_bytes)
+                    outcomes = list(outcomes)
+                    done = 0
+                    for i, o in zip(esc, re_out):
+                        if o is not None:
+                            outcomes[i] = o
+                            done += 1
+                    # occupancy telemetry must describe the *final* outcomes
+                    # (forced-plane equality covers max_count/overflowed too,
+                    # and the next level's plan is derived from these)
+                    lvl_max_count = max((o.max_count for o in outcomes
+                                         if o is not None), default=0)
+                    lvl_overflowed = any(o.overflowed for o in outcomes
+                                         if o is not None)
+                    health.record(
+                        "overflow_escalation",
+                        f"{done}/{len(esc)} patterns overflowed derived cap "
+                        f"{plan.match.cap}; re-run at base cap {cfg.match.cap}",
+                        level=level)
+                for pat, tau, out in zip(eval_pats, eval_taus, outcomes):
+                    if out is None:  # level timed out before this group ran
                         continue
+                    st = PatternStats(
+                        pattern=pat,
+                        support=out.support,
+                        tau=tau,
+                        frequent=out.frequent,
+                        embeddings_found=out.embeddings_found,
+                        overflowed=out.overflowed,
+                        blocks_run=out.blocks_run,
+                        max_count=out.max_count,
+                        estimated=getattr(out, "estimated", False),
+                    )
+                    searched += 1
+                    lvl_searched += 1
+                    all_stats.append(st)
+                    if st.frequent:
+                        frequent.append((pat, st.support))
+                        level_frequent.append(pat)
+            else:
+                seq_stats: List[PatternStats] = []
+                for pat, tau in zip(eval_pats, eval_taus):
                     if deadline is not None and time.monotonic() > deadline:
                         timed_out = True
                         break
-                    st = evaluate_pattern(g, dev_g, st.pattern, st.tau, cfg,
-                                          match_cfg=cfg.match,
-                                          block_order=block_order)
+                    with tracing.span("pattern", k=pat.k):
+                        st = evaluate_pattern(g, dev_g, pat, tau, cfg,
+                                              match_cfg=plan.match,
+                                              block_order=block_order)
                     lvl_dispatches += st.dispatches
-                    seq_stats[j] = st
-                    n_esc += 1
+                    seq_stats.append(st)
                     peak_bytes = max(
                         peak_bytes,
-                        graph_bytes + _device_bytes(cfg.match, cfg.metric,
-                                                    st.pattern.k, g.n))
-                if n_esc:
-                    health.record(
-                        "overflow_escalation",
-                        f"{n_esc} patterns overflowed derived cap "
-                        f"{plan.match.cap}; re-run at base cap "
-                        f"{cfg.match.cap}", level=level)
-            for st in seq_stats:
-                searched += 1
-                lvl_searched += 1
-                lvl_max_count = max(lvl_max_count, st.max_count)
-                lvl_overflowed |= st.overflowed
-                all_stats.append(st)
-                if st.frequent:
-                    frequent.append((st.pattern, st.support))
-                    level_frequent.append(st.pattern)
-        per_level[level] = {
-            "candidates": len(cp),
-            "searched": lvl_searched,
-            "pruned": lvl_pruned,
-            "frequent": len(level_frequent),
-            "dispatches": lvl_dispatches,
-            "max_count": int(lvl_max_count),
-            "overflowed": bool(lvl_overflowed),
-            "wall_s": time.monotonic() - level_t0,
-        }
-        if cfg.execution in ("auto", "sampled"):
-            per_level[level]["plan"] = plan.to_dict()
-            # planner-input telemetry: cross-plane per_level comparisons
-            # (the batched ≡ sequential ≡ auto tests) drop these keys
-            if tel is not None and tel.sampled is not None:
-                per_level[level]["sampled"] = tel.sampled
-            if tel is not None and tel.block_peaks is not None:
-                # block-id indexed peak occupancy — next level's draw weights
-                per_level[level]["block_peaks"] = [
-                    int(x) for x in tel.block_peaks]
-        if cfg.execution == "auto" and tel is not None:
-            per_level[level]["replans"] = int(getattr(tel, "replans", 0))
+                        graph_bytes + _device_bytes(plan.match, cfg.metric,
+                                                    pat.k, g.n))
+                # same overflow-escalation pass as the plane branch (the
+                # sequential plane carries an auto-derived cap too — mis_exact
+                # under execution="auto" in particular always lands here)
+                if plan.match.cap < cfg.match.cap and not timed_out:
+                    n_esc = 0
+                    for j, st in enumerate(seq_stats):
+                        if not st.overflowed:
+                            continue
+                        if deadline is not None and time.monotonic() > deadline:
+                            timed_out = True
+                            break
+                        tracing.count("escalated")
+                        with tracing.span("escalate", patterns=1):
+                            st = evaluate_pattern(g, dev_g, st.pattern, st.tau, cfg,
+                                                  match_cfg=cfg.match,
+                                                  block_order=block_order)
+                        lvl_dispatches += st.dispatches
+                        seq_stats[j] = st
+                        n_esc += 1
+                        peak_bytes = max(
+                            peak_bytes,
+                            graph_bytes + _device_bytes(cfg.match, cfg.metric,
+                                                        st.pattern.k, g.n))
+                    if n_esc:
+                        health.record(
+                            "overflow_escalation",
+                            f"{n_esc} patterns overflowed derived cap "
+                            f"{plan.match.cap}; re-run at base cap "
+                            f"{cfg.match.cap}", level=level)
+                for st in seq_stats:
+                    searched += 1
+                    lvl_searched += 1
+                    lvl_max_count = max(lvl_max_count, st.max_count)
+                    lvl_overflowed |= st.overflowed
+                    all_stats.append(st)
+                    if st.frequent:
+                        frequent.append((st.pattern, st.support))
+                        level_frequent.append(st.pattern)
+            per_level[level] = {
+                "candidates": len(cp),
+                "searched": lvl_searched,
+                "pruned": lvl_pruned,
+                "frequent": len(level_frequent),
+                "dispatches": lvl_dispatches,
+                "max_count": int(lvl_max_count),
+                "overflowed": bool(lvl_overflowed),
+            }
+            if cfg.execution in ("auto", "sampled"):
+                per_level[level]["plan"] = plan.to_dict()
+                # planner-input telemetry: cross-plane per_level comparisons
+                # (the batched ≡ sequential ≡ auto tests) drop these keys
+                if tel is not None and tel.sampled is not None:
+                    per_level[level]["sampled"] = tel.sampled
+                if tel is not None and tel.block_peaks is not None:
+                    # block-id indexed peak occupancy — next level's draw weights
+                    per_level[level]["block_peaks"] = [
+                        int(x) for x in tel.block_peaks]
+            if cfg.execution == "auto" and tel is not None:
+                per_level[level]["replans"] = int(getattr(tel, "replans", 0))
+        per_level[level]["wall_s"] = lvl.seconds
         if timed_out or not level_frequent:
             cp = []
         elif (cfg.generation == "merge"
@@ -685,29 +715,32 @@ def mine(g: DataGraph, cfg: MiningConfig, *, hooks=None,
             # merge keeps strict level-wise (k−1 → k) discipline
             cp = []
         else:
-            if cfg.generation == "merge":
-                cp = generate_new_patterns(level_frequent)
-            else:
-                # edge extension mixes vertex counts (that is the paper's
-                # point: same-vertex-count patterns land at different BFS
-                # levels)
-                cp = edge_extension_candidates(
-                    level_frequent, label_universe, max_k=cfg.max_pattern_size
-                )
-            searched_keys |= {canonical_key(st.pattern) for st in all_stats}
-            cp = [
-                p for p in cp
-                if p.k <= cfg.max_pattern_size and canonical_key(p) not in searched_keys
-            ]
+            with tracing.span("generate", level=level):
+                if cfg.generation == "merge":
+                    cp = generate_new_patterns(level_frequent)
+                else:
+                    # edge extension mixes vertex counts (that is the paper's
+                    # point: same-vertex-count patterns land at different BFS
+                    # levels)
+                    cp = edge_extension_candidates(
+                        level_frequent, label_universe, max_k=cfg.max_pattern_size
+                    )
+            with tracing.span("dedup", level=level):
+                searched_keys |= {canonical_key(st.pattern) for st in all_stats}
+                cp = [
+                    p for p in cp
+                    if p.k <= cfg.max_pattern_size and canonical_key(p) not in searched_keys
+                ]
         if hooks is not None:
-            hooks.on_level_end(loop_state(cp))
+            with tracing.span("hooks"):
+                hooks.on_level_end(loop_state(cp))
 
     return MiningResult(
         frequent=frequent,
         searched=searched,
         per_level=per_level,
         stats=all_stats,
-        elapsed_s=elapsed0 + (time.monotonic() - t0),
+        elapsed_s=elapsed0,
         timed_out=timed_out,
         peak_device_bytes=peak_bytes,
         health=health,

@@ -50,8 +50,8 @@ import numpy as np
 from .graph import DataGraph, DeviceGraph
 from .plan import PatternPlan
 
-__all__ = ["MatchConfig", "match_block", "batch_checks", "edge_exists",
-           "device_graph_tuple", "transient_match_bytes"]
+__all__ = ["MatchConfig", "match_block", "match_block_lanes", "batch_checks",
+           "edge_exists", "device_graph_tuple", "transient_match_bytes"]
 
 
 # Register the graph/plan dataclasses as pytrees so they pass through jit
@@ -263,10 +263,12 @@ def _expand_level(g: DeviceGraph, plan: PatternPlan, emb, count, level: int,
 
     emb: (cap, k) int32 frontier (columns ≥ level are -1); count: () int32
     valid rows.  Returns (out_emb (cap, k) int32, out_count () int32,
-    found () int32, overflowed () bool); survivors are packed in
-    (chunk, row, position) order — the order the greedy-mIS metric consumes.
-    Dispatches to the fused Pallas kernel when cfg.expansion == "pallas"
-    (bit-identical to the single-phase pipeline below).
+    found () int32, overflowed () bool, lanes () int32); survivors are
+    packed in (chunk, row, position) order — the order the greedy-mIS
+    metric consumes.  ``lanes`` counts the candidate lanes the tiles walked,
+    ⌈active rows / T⌉·T·C per chunk (0 on the Pallas plane, which does not
+    report it).  Dispatches to the fused Pallas kernel when cfg.expansion ==
+    "pallas" (bit-identical to the single-phase pipeline below).
 
     The work follows the frontier, not its capacity: chunk c walks, in
     tiles of ``_row_tile(cfg)`` rows, only the rows whose degree reaches into
@@ -282,7 +284,8 @@ def _expand_level(g: DeviceGraph, plan: PatternPlan, emb, count, level: int,
     if cfg.expansion == "pallas":
         from repro.kernels.frontier_expand.ops import frontier_expand_level
 
-        return frontier_expand_level(g, plan, emb, count, level, cfg)
+        return frontier_expand_level(g, plan, emb, count, level, cfg) \
+            + (jnp.int32(0),)
     cap, C, k = cfg.cap, cfg.chunk, plan.k
     T = _row_tile(cfg)
     i = level  # python int (static): column being filled
@@ -397,26 +400,29 @@ def _expand_level(g: DeviceGraph, plan: PatternPlan, emb, count, level: int,
     n_chunks = jnp.minimum((jnp.max(deg) + C - 1) // C, cfg.max_chunks)
 
     def chunk_body(c, carry):
-        out_emb, out_count, found, ovf = carry
+        out_emb, out_count, found, ovf, lanes = carry
         # the rows whose degree reaches into chunk c, in row order
         active = deg[:n_rows] > c * C
         n_active = active.sum().astype(jnp.int32)
         slot = jnp.cumsum(active).astype(jnp.int32) - 1
         act = jnp.zeros((n_slots + 1,), jnp.int32).at[
             jnp.where(active, slot, _DROP)].set(row_ids, mode="drop")
+        n_tiles = (n_active + T - 1) // T
         out_emb, out_count, found, n_phase1 = jax.lax.fori_loop(
-            0, (n_active + T - 1) // T,
+            0, n_tiles,
             lambda t, cr: tile_body(t, c, act, n_active, cr),
             (out_emb, out_count, found, jnp.int32(0)))
         # phase-1 drop: results may be incomplete
-        return out_emb, out_count, found, ovf | (n_phase1 > cap)
+        return (out_emb, out_count, found, ovf | (n_phase1 > cap),
+                lanes + n_tiles * (T * C))
 
     out_emb0 = jnp.full((cap + 1, k), -1, dtype=jnp.int32)
-    out_emb, out_count, found, ovf = jax.lax.fori_loop(
+    out_emb, out_count, found, ovf, lanes = jax.lax.fori_loop(
         0, n_chunks, chunk_body,
-        (out_emb0, jnp.int32(0), jnp.int32(0), jnp.bool_(False)),
+        (out_emb0, jnp.int32(0), jnp.int32(0), jnp.bool_(False),
+         jnp.int32(0)),
     )
-    return out_emb[:cap], out_count, found, ovf
+    return out_emb[:cap], out_count, found, ovf, lanes
 
 
 def batch_checks(plans: PatternPlan):
@@ -460,14 +466,28 @@ def match_block(g: DeviceGraph, plan: PatternPlan, block_start, cfg: MatchConfig
               consumes (`core/planner.py`); when `overflowed` is set the true
               need exceeded `cap` and `peak` is only a lower bound.
     """
+    return match_block_lanes(g, plan, block_start, cfg, checks)[:5]
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def match_block_lanes(g: DeviceGraph, plan: PatternPlan, block_start,
+                      cfg: MatchConfig, checks=None):
+    """`match_block`'s outputs and a sixth, ``lanes`` (2,) int32: the
+    expansion's candidate lanes walked and those that passed every filter,
+    summed over its levels (both 0 on the Pallas plane).  The batched step
+    returns ``lanes`` per pattern.  Jitted like `match_block`, so that a
+    step program traced for another bucket width reuses its trace."""
     emb, count = _init_roots(g, plan, block_start, cfg)
     found = count
     peak = count
     overflowed = jnp.bool_(False)
+    processed = useful = jnp.int32(0)
     for level in range(1, plan.k):
-        emb, count, lvl_found, lvl_ovf = _expand_level(
+        emb, count, lvl_found, lvl_ovf, lvl_lanes = _expand_level(
             g, plan, emb, count, level, cfg, checks)
         overflowed |= lvl_ovf | (lvl_found > cfg.cap)
         found = lvl_found
         peak = jnp.maximum(peak, count)
-    return emb, count, found, overflowed, peak
+        processed += lvl_lanes
+        useful += lvl_found
+    return emb, count, found, overflowed, peak, jnp.stack([processed, useful])

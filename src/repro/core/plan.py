@@ -25,6 +25,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import tracing
 from .graph import DataGraph
 from .pattern import Pattern
 
@@ -80,6 +81,7 @@ def stack_plans(plans: Sequence[PatternPlan]) -> PatternPlan:
 def make_plan(pat: Pattern, graph: Optional[DataGraph] = None) -> PatternPlan:
     if not pat.is_connected():
         raise ValueError("can only plan connected patterns")
+    tracing.count("plans_built")
     k = pat.k
     und = pat.undirected_adj()
     out_deg = pat.adj.sum(axis=1).astype(np.int32)

@@ -54,6 +54,7 @@ from .graph import DataGraph, DeviceGraph
 from .matcher import MatchConfig, transient_match_bytes
 from .pattern import Pattern
 from .plan import make_plan
+from . import tracing
 
 __all__ = [
     "evaluate_level_sampled", "ht_estimate", "ht_interval",
@@ -455,7 +456,6 @@ def evaluate_level_sampled(
     hooks=None,
     block_order: Optional[np.ndarray] = None,
     sample_rounds: int = 1,
-    counters: Optional[Dict[str, int]] = None,
 ) -> Tuple[List[Optional[PatternOutcome]], bool, LevelTelemetry]:
     """Evaluate a candidate level with the sampled plane (module docstring).
 
@@ -482,9 +482,9 @@ def evaluate_level_sampled(
     capture mode, recording each (pattern, block) raw match result; the
     exact escalation then walks the full schedule but *replays* sampled
     positions with the cheap update-only step instead of re-matching them
-    (`evaluate_level_batched`'s ``replay``).  ``counters`` threads through
-    to the escalation pass only — ``{"match_blocks", "replay_blocks"}``
-    counts prove no sampled block is ever re-matched.
+    (`evaluate_level_batched`'s ``replay``).  The query's
+    ``match_blocks``/``replay_blocks`` counters (`core/tracing.py`) prove
+    no sampled block is ever re-matched.
 
     ``hooks`` extends the batched resume surface with the sampled-phase
     cursor: ``resume_sampled()`` → the recorded phase dict or None, and
@@ -631,7 +631,8 @@ def evaluate_level_sampled(
                 if deadline is not None and time.monotonic() > deadline:
                     timed_out = True
                     break
-                plans = [make_plan(patterns[i], host_g) for i in idxs]
+                with tracing.span("plan_build", k=k, patterns=len(idxs)):
+                    plans = [make_plan(patterns[i], host_g) for i in idxs]
                 ys, outs, disp, bpeaks, g_timed, rep = sample_group(
                     dev_g, plans, [taus[i] for i in idxs], metric, cfg, n=n,
                     sampled_ids=sampled_ids_r, deadline=deadline,
@@ -740,7 +741,7 @@ def evaluate_level_sampled(
             host_g, dev_g, [patterns[i] for i in esc_idx],
             [taus[i] for i in esc_idx], metric, cfg, complete=complete,
             deadline=deadline, max_batch=max_batch, hooks=adapter,
-            block_order=block_order, replay=replay_list, counters=counters)
+            block_order=block_order, replay=replay_list)
         timed_out |= esc_timed
         for i, o in zip(esc_idx, outs2):
             outcomes[i] = o

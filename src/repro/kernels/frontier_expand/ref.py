@@ -24,6 +24,6 @@ def frontier_expand_ref(g, plan, emb, count, level: int, cfg: MatchConfig):
     check back in).
     """
     cfg = dataclasses.replace(cfg, expansion="xla", two_phase=False)
-    out_emb, out_count, found, ovf = _expand_level(g, plan, emb, count,
-                                                   level, cfg)
+    out_emb, out_count, found, ovf, _ = _expand_level(g, plan, emb, count,
+                                                      level, cfg)
     return out_emb, out_count, found, ovf | (found > cfg.cap)

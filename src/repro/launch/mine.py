@@ -259,6 +259,10 @@ def main(argv=None) -> int:
             # result order — what the CI resume-smoke diffs against an
             # uninterrupted run
             "frequent": [[p.k, int(s)] for p, s in res.frequent],
+            # the query's counters and self seconds per span name
+            # (core/tracing.py, docs/metrics.md "Spans and counters")
+            "counters": res.trace.counters,
+            "span_self_s": res.trace.self_seconds(),
         }
         with open(args.json, "w") as f:
             json.dump(out, f, indent=2, sort_keys=True)

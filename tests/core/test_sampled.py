@@ -603,19 +603,21 @@ def _level_fixture(metric="mis", fraction=0.5):
 def test_escalation_reuse_never_rematches_sampled_blocks():
     """Acceptance: with τ one above every true support nothing early-exits
     and nothing prunes, so the escalation walks the full schedule for
-    every pattern — and the counters prove each sampled block is replayed,
-    never re-matched.  All-escalate also means the settled-set CI width
+    every pattern — and the query's counters prove each sampled block is
+    replayed, never re-matched.  All-escalate also means the settled-set CI width
     has no samples: `ci_width_mean` must be None (JSON null), not NaN."""
+    from repro.core import tracing
     from repro.core.sampled import evaluate_level_sampled
 
     g, dev_g, cfg, pats, plan, exact = _level_fixture("mis", 0.5)
     taus = [o.support + 1 for o in exact]
     m = -(-g.n // cfg.match.root_block)
-    counters = {}
-    outs, timed, tel = evaluate_level_sampled(
-        g, dev_g, pats, taus, "mis", cfg.match, sample=plan.sample,
-        confidence=cfg.confidence, escalate=True, max_batch=64,
-        sample_rounds=1, counters=counters)
+    with tracing.query() as trace:
+        outs, timed, tel = evaluate_level_sampled(
+            g, dev_g, pats, taus, "mis", cfg.match, sample=plan.sample,
+            confidence=cfg.confidence, escalate=True, max_batch=64,
+            sample_rounds=1)
+    counters = trace.counters
     assert not timed
     s = tel.sampled
     assert s["escalated"] == len(pats) and s["pruned"] == 0
@@ -626,12 +628,14 @@ def test_escalation_reuse_never_rematches_sampled_blocks():
         assert not o.estimated
         assert (o.support, o.embeddings_found, o.overflowed) \
             == (e.support, e.embeddings_found, e.overflowed)
-    # one k=2 group (max_batch ≥ P): the full walk visits every block
-    # exactly once per group — sampled positions via the update-only
-    # replay step, the rest via real match steps
+    # one k=2 group (max_batch ≥ P): the sample pass matches each sampled
+    # block once, and the escalation's full walk visits every block exactly
+    # once per group — sampled positions via the update-only replay step,
+    # the rest via real match steps
     n_groups = -(-len(pats) // 64)
     assert counters["replay_blocks"] == n_groups * s["n_sample"]
-    assert counters["match_blocks"] == n_groups * (m - s["n_sample"])
+    assert counters["match_blocks"] == n_groups * s["n_sample"] \
+        + n_groups * (m - s["n_sample"])
 
 
 def test_adaptive_rounds_grow_coverage_until_undecided_stops_shrinking():

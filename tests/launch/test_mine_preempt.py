@@ -39,6 +39,9 @@ def _norm(json_path):
     d = json.loads(Path(json_path).read_text())
     d.pop("elapsed_s", None)
     d.pop("health", None)  # a resumed run records recoveries; oracle never
+    # counters and span times describe this process's share of the run
+    d.pop("counters", None)
+    d.pop("span_self_s", None)
     for lvl in d.get("per_level", {}).values():
         lvl.pop("wall_s", None)
     return d
