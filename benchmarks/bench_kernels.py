@@ -78,7 +78,7 @@ def main() -> None:
     from repro.core.graph import DeviceGraph
     from repro.core.matcher import match_block
     from repro.core.mis import bitmap_init, mis_greedy_update as mgu
-    from repro.core.plan import make_plan, stack_plans
+    from repro.core.plan import make_plan, put_plan, stack_plans
 
     bn = 1000 if SMOKE else 4000
     src = np.repeat(np.arange(bn), 2)
@@ -88,8 +88,9 @@ def main() -> None:
     dev_bg = DeviceGraph.from_host(bg)
     mcfg = MatchConfig.for_graph(bg, cap=64, root_block=64)
     pats = initial_candidates(bg)[:16]
-    plans = [make_plan(p, bg) for p in pats]
-    stacked = stack_plans(plans)
+    host_plans = [make_plan(p, bg) for p in pats]
+    stacked = put_plan(stack_plans(host_plans))
+    plans = [put_plan(p) for p in host_plans]
     state = _state_init("mis", 16, 2, bn)
     taus16 = jnp.full((16,), 10**9, jnp.int32)
     step = _step_fn("mis", 2, mcfg)
@@ -119,7 +120,7 @@ def main() -> None:
     from repro.core.generation import generate_new_patterns
     from repro.core.graph import DeviceGraph
     from repro.core.matcher import match_block
-    from repro.core.plan import make_plan as _make_plan
+    from repro.core.plan import make_plan as _make_plan, put_plan as _put_plan
 
     fn_n = 500 if SMOKE else 4000
     fdeg = 4
@@ -135,7 +136,7 @@ def main() -> None:
     fpats = initial_candidates(fg)
     fk3 = generate_new_patterns(fpats[:6])
     assert fk3, "graph yields no size-3 candidates"
-    fplan = _make_plan(fk3[0], fg)
+    fplan = _put_plan(_make_plan(fk3[0], fg))
     assert fplan.k == 3
 
     geo = f"cap{fcfg.cap}C{fcfg.chunk}k{fplan.k}"

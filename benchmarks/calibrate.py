@@ -72,7 +72,7 @@ def fit_cost_model(iters: int = 20) -> dict:
     from repro.core import MatchConfig, build_graph, initial_candidates
     from repro.core.batched import _state_init, _step_fn
     from repro.core.graph import DeviceGraph
-    from repro.core.plan import make_plan, stack_plans
+    from repro.core.plan import make_plan, put_plan, stack_plans
     from repro.core.planner import CALIBRATION_SCHEMA, CostModel
 
     rng = np.random.default_rng(0)
@@ -95,7 +95,7 @@ def fit_cost_model(iters: int = 20) -> dict:
             chunk=chunk, max_chunks=1, two_phase=False)
         step = _step_fn(metric, k, cfg, unbatched=bucket == 1)
         sel = [plans[i % len(plans)] for i in range(bucket)]
-        stacked = stack_plans(sel)
+        stacked = put_plan(stack_plans(sel))
         state = _state_init(metric, bucket, k, n)
         taus = jnp.full((bucket,), 10**9, jnp.int32)
 

@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import MatchConfig, MiningConfig, make_plan, match_block, mine
+from repro.core import (MatchConfig, MiningConfig, make_plan, match_block, mine,
+                        put_plan)
 from repro.core.graph import DeviceGraph
 from repro.data.synthetic import rmat_graph
 from repro.models.gnn.common import GraphBatch
@@ -29,7 +30,7 @@ def motif_features(g, patterns, cfg):
     dev_g = DeviceGraph.from_host(g)
     feats = np.zeros((g.n, len(patterns)), np.float32)
     for j, pat in enumerate(patterns):
-        plan = make_plan(pat, g)
+        plan = put_plan(make_plan(pat, g))
         for b in range(0, g.n, cfg.root_block):
             emb, count, _, _, _ = match_block(dev_g, plan, jnp.int32(b), cfg)
             rows = np.asarray(emb[: int(count)]).reshape(-1)

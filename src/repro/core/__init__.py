@@ -16,7 +16,7 @@ from .generation import (
     size2_patterns,
 )
 from .health import HealthEvent, RunHealth
-from .plan import PatternPlan, make_plan
+from .plan import PatternPlan, make_plan, put_plan
 from .matcher import MatchConfig, match_block
 from .planner import (
     CostModel,
@@ -51,7 +51,7 @@ __all__ = [
     "core_graphs", "core_groups", "edge_extension_candidates",
     "generate_new_patterns", "size2_patterns",
     "HealthEvent", "RunHealth",
-    "PatternPlan", "make_plan", "MatchConfig", "match_block",
+    "PatternPlan", "make_plan", "put_plan", "MatchConfig", "match_block",
     "CostModel", "ExecutionPlanner", "LevelPlan", "block_degree_stat",
     "load_calibration", "root_block_order",
     "evaluate_level_sampled", "ht_estimate", "ht_interval",

@@ -55,7 +55,7 @@ import numpy as np
 
 from .graph import DataGraph, DeviceGraph
 from .pattern import Pattern
-from .plan import PatternPlan, make_plan, stack_plans
+from .plan import PatternPlan, make_plan, put_plan, stack_plans
 from .matcher import (MatchConfig, batch_checks, match_block,
                       match_block_lanes, transient_match_bytes)
 from . import tracing
@@ -454,10 +454,11 @@ def _mine_group(
 
     Each block is a ``flexis.block`` span (`core/tracing.py`) holding its
     ``dispatch``, ``pull``, ``account``, ``restack`` and ``hooks`` spans;
-    the group's set-up is a ``plan_build`` span.  The query's counters
-    gain ``match_blocks``/``replay_blocks``, ``host_pulls``, ``restacks``
-    and, on the XLA expansion, ``lanes_processed``/``lanes_useful`` of the
-    live rows.
+    the group's set-up is a ``plan_build`` span, in which the group's
+    host-stacked plan goes to the device in one put.  The query's counters
+    gain ``plan_puts``, ``match_blocks``/``replay_blocks``, ``host_pulls``,
+    ``restacks`` and, on the XLA expansion,
+    ``lanes_processed``/``lanes_useful`` of the live rows.
 
     ``block_order`` is the static root-block schedule — a permutation of
     block ids from `planner.root_block_order` (None = vertex-id order), or
@@ -535,8 +536,11 @@ def _mine_group(
     with tracing.span("plan_build", k=k, patterns=P0):
         state = (_state_init(metric, bucket_map.size, k, n) if resume is None
                  else jax.tree_util.tree_map(jnp.asarray, resume.state))
-        plans_cur = _gather_rows(stack_plans(plans),
-                                 np.where(bucket_map >= 0, bucket_map, 0))
+        # the bucket's rows are picked on the host; the group's plan then
+        # goes to the device in one put
+        rows = np.where(bucket_map >= 0, bucket_map, 0)
+        plans_cur = put_plan(jax.tree_util.tree_map(lambda a: a[rows],
+                                                    stack_plans(plans)))
         taus_dev = bucket_taus(bucket_map)
 
     timed_out = False

@@ -46,7 +46,7 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .graph import DataGraph, DeviceGraph
 from .pattern import Pattern
-from .plan import PatternPlan, make_plan, stack_plans
+from .plan import PatternPlan, make_plan, put_plan, stack_plans
 from .matcher import (MatchConfig, batch_checks, match_block,
                       transient_match_bytes)
 from . import mis as mis_lib
@@ -68,8 +68,8 @@ def mining_mesh(axis: str = "workers", devices=None) -> Mesh:
 
 def _replicated(tree, mesh: Mesh):
     """``tree`` placed whole on every device of ``mesh``, once — the graph
-    and the plans every step reads, instead of one device's copy moved to
-    the mesh at each dispatch."""
+    every step reads, instead of one device's copy moved to the mesh at
+    each dispatch (the plans go the same way through `put_plan`)."""
     return jax.device_put(tree, NamedSharding(mesh, P()))
 
 
@@ -282,8 +282,9 @@ def iter_batched_supports(
     cfg = match_cfg or MatchConfig.for_graph(host_g)
     dev_g = _replicated(DeviceGraph.from_host(host_g), mesh)
     with tracing.span("plan_build", k=k, patterns=len(patterns)):
-        plans = _replicated(
-            stack_plans([make_plan(p, host_g) for p in patterns]), mesh)
+        plans = put_plan(
+            stack_plans([make_plan(p, host_g) for p in patterns]),
+            NamedSharding(mesh, P()))
     n = host_g.n
     P_ = len(patterns)
     taus_np = np.asarray(taus, np.int64)
@@ -530,7 +531,7 @@ def distributed_support(
     ndev = int(np.prod(list(mesh.shape.values())))
     cfg = match_cfg or MatchConfig.for_graph(host_g)
     dev_g = _replicated(DeviceGraph.from_host(host_g), mesh)
-    plan = _replicated(make_plan(pat, host_g), mesh)
+    plan = put_plan(make_plan(pat, host_g), NamedSharding(mesh, P()))
     n = host_g.n
     bitmap = mis_lib.bitmap_init(n)
     count = jnp.int32(0)

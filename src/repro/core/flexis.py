@@ -23,7 +23,7 @@ from .pattern import Pattern
 from .canonical import canonical_key, dedupe_patterns
 from .generation import edge_extension_candidates, generate_new_patterns
 from .matcher import MatchConfig, match_block, transient_match_bytes
-from .plan import make_plan
+from .plan import make_plan, put_plan
 from .planner import CostModel, ExecutionPlanner, LevelPlan
 from . import planner as planner_lib
 from . import batched as batched_lib
@@ -252,7 +252,8 @@ def evaluate_pattern(
     fewer dispatches — and solves MIS exactly on host.
     """
     mcfg = cfg.match if match_cfg is None else match_cfg
-    plan = make_plan(pat, host_g)
+    # one put for the whole block loop, not one per `match_block` call
+    plan = put_plan(make_plan(pat, host_g))
     k = pat.k
     n = host_g.n
     metric = cfg.metric

@@ -12,9 +12,14 @@ pattern, on the host:
   * anchor = for each non-root vertex, one already-ordered neighbor whose
              adjacency list is gathered to enumerate candidates.
 
-All plan fields are *data* (jnp arrays), not static attributes, so the jitted
+All plan fields are *data* (arrays), not static attributes, so the jitted
 matcher compiles once per pattern size k and is reused across every pattern
 of that size — crucial when a mining level evaluates hundreds of candidates.
+
+A plan is built, and a group of plans stacked, as host (numpy) arrays; the
+consumer then puts what it runs on the device in one `put_plan` call (one
+per candidate group on the batched plane), instead of one transfer per
+field per plan.
 """
 from __future__ import annotations
 
@@ -23,18 +28,18 @@ from typing import Optional, Sequence
 
 import numpy as np
 import jax
-import jax.numpy as jnp
 
 from . import tracing
 from .graph import DataGraph
 from .pattern import Pattern
 
-__all__ = ["PatternPlan", "make_plan", "stack_plans"]
+__all__ = ["PatternPlan", "make_plan", "stack_plans", "put_plan"]
 
 
 @dataclasses.dataclass(frozen=True)
 class PatternPlan:
-    """Device-side matching plan for one pattern.
+    """Matching plan for one pattern: numpy fields as `make_plan` and
+    `stack_plans` build it, device arrays once `put_plan` has placed it.
 
     k:            pattern size (the only static field).
     root_label:   int32 scalar.
@@ -49,16 +54,16 @@ class PatternPlan:
     """
 
     k: int
-    root_label: jnp.ndarray
-    root_min_out: jnp.ndarray
-    root_min_in: jnp.ndarray
-    anchor_pos: jnp.ndarray
-    anchor_out: jnp.ndarray
-    cand_label: jnp.ndarray
-    min_out: jnp.ndarray
-    min_in: jnp.ndarray
-    check_out: jnp.ndarray
-    check_in: jnp.ndarray
+    root_label: np.ndarray
+    root_min_out: np.ndarray
+    root_min_in: np.ndarray
+    anchor_pos: np.ndarray
+    anchor_out: np.ndarray
+    cand_label: np.ndarray
+    min_out: np.ndarray
+    min_in: np.ndarray
+    check_out: np.ndarray
+    check_in: np.ndarray
     order: tuple  # host-side: order[i] = original pattern vertex at step i
 
 
@@ -68,14 +73,23 @@ def stack_plans(plans: Sequence[PatternPlan]) -> PatternPlan:
     The per-plan host-side ``order`` metadata is dropped (set to ``()``) so
     every stacked plan of a given k shares one treedef — jit programs keyed on
     the plan pytree then cache-hit across levels instead of retracing per
-    stack.
+    stack.  Stacks on the host: the result is numpy, for one `put_plan`.
     """
     assert len(plans) > 0, "cannot stack zero plans"
     k = plans[0].k
     assert all(p.k == k for p in plans), "plans must share pattern size"
     leaves = [jax.tree_util.tree_flatten(p)[0] for p in plans]
-    stacked = [jnp.stack([ln[i] for ln in leaves]) for i in range(len(leaves[0]))]
+    stacked = [np.stack([ln[i] for ln in leaves]) for i in range(len(leaves[0]))]
     return PatternPlan(k, *stacked, order=())
+
+
+def put_plan(plan: PatternPlan, device=None) -> PatternPlan:
+    """``plan`` (one plan or a stacked group) on the device in one
+    `jax.device_put` of the whole pytree; ``device`` as `jax.device_put`
+    takes it (a sharding places it on a mesh).  Counted as ``plan_puts``
+    in the query's record."""
+    tracing.count("plan_puts")
+    return jax.device_put(plan, device)
 
 
 def make_plan(pat: Pattern, graph: Optional[DataGraph] = None) -> PatternPlan:
@@ -149,15 +163,15 @@ def make_plan(pat: Pattern, graph: Optional[DataGraph] = None) -> PatternPlan:
     in_o = in_deg[order]
     return PatternPlan(
         k=k,
-        root_label=jnp.int32(labels_o[0]),
-        root_min_out=jnp.int32(out_o[0]),
-        root_min_in=jnp.int32(in_o[0]),
-        anchor_pos=jnp.asarray(anchor_pos),
-        anchor_out=jnp.asarray(anchor_out),
-        cand_label=jnp.asarray(labels_o, jnp.int32),
-        min_out=jnp.asarray(out_o, jnp.int32),
-        min_in=jnp.asarray(in_o, jnp.int32),
-        check_out=jnp.asarray(check_out),
-        check_in=jnp.asarray(check_in),
+        root_label=np.int32(labels_o[0]),
+        root_min_out=np.int32(out_o[0]),
+        root_min_in=np.int32(in_o[0]),
+        anchor_pos=anchor_pos,
+        anchor_out=anchor_out,
+        cand_label=labels_o.astype(np.int32),
+        min_out=out_o.astype(np.int32),
+        min_in=in_o.astype(np.int32),
+        check_out=check_out,
+        check_in=check_in,
         order=tuple(order),
     )

@@ -48,6 +48,112 @@ def test_stack_plans_shapes():
     assert stacked.check_out.shape == (3, 2, 2)
 
 
+# Each field's dtype and shape for a size-k plan, in pytree order.
+_PLAN_FIELDS = (
+    ("root_label", np.int32, lambda k: ()),
+    ("root_min_out", np.int32, lambda k: ()),
+    ("root_min_in", np.int32, lambda k: ()),
+    ("anchor_pos", np.int32, lambda k: (k,)),
+    ("anchor_out", np.bool_, lambda k: (k,)),
+    ("cand_label", np.int32, lambda k: (k,)),
+    ("min_out", np.int32, lambda k: (k,)),
+    ("min_in", np.int32, lambda k: (k,)),
+    ("check_out", np.bool_, lambda k: (k, k)),
+    ("check_in", np.bool_, lambda k: (k, k)),
+)
+
+# The plans of `test_stack_plans_shapes`' graph (three edges, then two
+# 3-vertex patterns) as the matcher saw them when each field was built on
+# the device: the fields in `_PLAN_FIELDS` order, then ``order``.
+_DEVICE_BUILT = (
+    (0, 1, 0, [0, 0], [0, 1], [0, 0], [1, 0], [0, 1],
+     [[0, 0], [0, 0]], [[0, 0], [0, 0]], (0, 1)),
+    (0, 1, 0, [0, 0], [0, 1], [0, 1], [1, 0], [0, 1],
+     [[0, 0], [0, 0]], [[0, 0], [0, 0]], (0, 1)),
+    (0, 0, 1, [0, 0], [0, 0], [0, 1], [0, 1], [1, 0],
+     [[0, 0], [0, 0]], [[0, 0], [0, 0]], (1, 0)),
+    (0, 0, 2, [0, 0, 0], [0, 0, 0], [0, 0, 0], [0, 1, 1], [2, 0, 0],
+     [[0, 0, 0], [0, 0, 0], [0, 0, 0]], [[0, 0, 0], [0, 0, 0], [0, 0, 0]],
+     (0, 1, 2)),
+    (0, 0, 2, [0, 0, 1], [0, 0, 1], [0, 0, 0], [0, 2, 1], [2, 0, 1],
+     [[0, 0, 0], [0, 0, 0], [1, 0, 0]], [[0, 0, 0], [0, 0, 0], [0, 0, 0]],
+     (0, 1, 2)),
+)
+
+
+def _plan_patterns():
+    from repro.core.generation import generate_new_patterns
+
+    g = rmat_graph(100, 600, n_labels=2, seed=2)
+    edges = initial_candidates(g)
+    k3 = [p for p in generate_new_patterns(edges) if p.k == 3]
+    return g, edges[:3] + k3[:2]
+
+
+def test_make_plan_builds_host_arrays_with_the_device_plans_values():
+    import jax
+
+    from repro.core.plan import put_plan
+
+    g, pats = _plan_patterns()
+    for pat, want in zip(pats, _DEVICE_BUILT, strict=True):
+        plan = make_plan(pat, g)
+        placed = put_plan(plan)
+        assert plan.order == want[-1] and placed.order == plan.order
+        for (name, dtype, shape), value in zip(_PLAN_FIELDS, want):
+            host, dev = getattr(plan, name), getattr(placed, name)
+            assert not isinstance(host, jax.Array), name
+            assert host.dtype == dtype and host.shape == shape(pat.k), name
+            np.testing.assert_array_equal(host, np.asarray(value, dtype))
+            assert isinstance(dev, jax.Array), name
+            # one abstract signature on both sides: a jitted step that
+            # takes either traces once
+            assert jax.typeof(dev) == jax.typeof(host), name
+            np.testing.assert_array_equal(np.asarray(dev), host)
+
+
+def test_stack_plans_stacks_on_the_host():
+    import jax
+
+    g, pats = _plan_patterns()
+    for group in (pats[:3], pats[3:]):
+        stacked = stack_plans([make_plan(p, g) for p in group])
+        k = group[0].k
+        assert stacked.k == k and stacked.order == ()
+        for name, dtype, shape in _PLAN_FIELDS:
+            leaf = getattr(stacked, name)
+            assert isinstance(leaf, np.ndarray), name
+            assert not isinstance(leaf, jax.Array), name
+            assert leaf.dtype == dtype, name
+            assert leaf.shape == (len(group),) + shape(k), name
+
+
+def test_a_host_plan_and_its_put_share_one_trace():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.matcher import match_block_lanes
+    from repro.core.plan import put_plan
+
+    g, pats = _plan_patterns()
+    dg = DeviceGraph.from_host(g)
+    cfg = MatchConfig.for_graph(g, cap=256, root_block=32)
+    plan = make_plan(pats[3], g)
+    traced = []
+
+    @jax.jit
+    def step(pl, start):
+        traced.append(1)
+        return match_block_lanes.__wrapped__(dg, pl, start, cfg)
+
+    want = step(plan, jnp.int32(0))
+    got = step(put_plan(plan), jnp.int32(0))
+    assert len(traced) == 1
+    for a, b in zip(jax.tree_util.tree_leaves(want),
+                    jax.tree_util.tree_leaves(got)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_unbatched_step_bit_identical_to_vmapped():
     """The P=1 no-vmap fast path must return exactly what the vmapped
     size-1 bucket returns (it replaces it transparently in _mine_group)."""

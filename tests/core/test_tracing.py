@@ -117,6 +117,40 @@ def test_answers_unchanged_by_the_record(execution, batched_twice):
     assert res.trace.counters["plans_built"] >= res.searched
 
 
+@pytest.mark.parametrize("execution", sorted(DIGESTS))
+def test_one_plan_put_per_candidate_group(execution, batched_twice,
+                                          monkeypatch):
+    """Each candidate group puts its stacked plan on the device once (each
+    pattern its own plan, on the sequential plane): no path puts plan
+    fields one by one.  Planning and answers are as before."""
+    from repro.core import batched as batched_lib
+    from repro.core import flexis as flexis_lib
+    from repro.core import sampled as sampled_lib
+
+    groups = []
+
+    def counted(fn):
+        def run(*args, **kwargs):
+            groups.append(fn.__name__)
+            return fn(*args, **kwargs)
+        return run
+
+    for module, name in ((batched_lib, "_mine_group"),
+                         (sampled_lib, "_mine_group"),
+                         (flexis_lib, "evaluate_pattern")):
+        monkeypatch.setattr(module, name, counted(getattr(module, name)))
+    res = mine(*_query(execution))
+    c = res.trace.counters
+    assert groups and c["plan_puts"] == len(groups)
+    assert _digest(res) == DIGESTS[execution]
+    if execution == "batched":
+        warm = batched_twice[1].trace.counters
+        assert c["plan_puts"] == warm["plan_puts"]
+        assert c["plans_built"] == warm["plans_built"]
+        # a third query in the process: still every program cached
+        assert c.get("compiles", 0) == 0
+
+
 def test_outside_a_query_spans_only_annotate():
     with tracing.span("block") as s:
         tracing.count("host_pulls")
