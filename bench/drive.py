@@ -1,7 +1,15 @@
 """How a window drives ``mine()``, for every traffic mix.
 
-A traffic file (``bench/traffic/<mix>.json``) holds the query (``metric``,
-``sigma``, ``lam``, ``cap``, ``execution``) and its ``loop``:
+A traffic file (``bench/traffic/<mix>.json``) holds the query and its
+loop.  The driver reads ``OWN`` itself: the ``metric`` (whose rule file,
+``bench/references/<metric>.py``, decides ``correct``), ``sigma``, ``lam``,
+the frontier ``cap``, the ``loop`` and ``warm_up_s``.  The keys of
+``PASSED`` choose how the program computes an answer, not what the answer
+is, and reach ``MiningConfig`` as they stand where the file sets them.  Any
+other key is refused, as is a metric with no rule file (`check` raises
+`BadTraffic` before anything is set up): ``complete``, ``escalate``,
+``root_order`` and ``generation`` would change the answer or the block
+order the reference follows.  The loops:
 
 * ``"cut"``: one query called at the window's start and cut by its own time
   limit at the window's end; should it finish first, the next one starts.
@@ -20,6 +28,8 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -29,6 +39,9 @@ from bench.recorder import Recorder, Spans
 from bench.rmat import Graph
 
 LOOPS = ("cut", "closed")
+OWN = ("loop", "metric", "sigma", "lam", "cap", "warm_up_s")
+PASSED = ("execution", "batch_patterns", "blocks_per_super", "sample_fraction",
+          "confidence", "sample_seed", "sample_rounds")
 
 # Benchmark-side spans: (span, program module, attribute), found by name.
 SPANS = (
@@ -46,16 +59,38 @@ class Query:
     pairs: float                # (candidate, root vertex) pairs settled
 
 
-class Cell:
-    """One configuration under one traffic mix, on the program."""
+class BadTraffic(ValueError):
+    """A traffic file the harness cannot drive or check."""
 
-    def __init__(self, config: dict, traffic: dict, graph: Graph):
+
+def check(traffic: dict, rules: Optional[Path] = None) -> ModuleType:
+    """The rule of the traffic's metric (`reference.rule`, from ``rules``
+    or bench/references/); raises `BadTraffic` for a key the driver neither
+    owns nor passes on, a loop it does not know, or a metric with no rule
+    file."""
+    unknown = sorted(set(traffic) - set(OWN) - set(PASSED))
+    if unknown:
+        raise BadTraffic(f"traffic key(s) {unknown} neither read by the driver "
+                         f"{list(OWN)} nor passed to MiningConfig {list(PASSED)}")
+    if traffic["loop"] not in LOOPS:
+        raise BadTraffic(f"loop {traffic['loop']!r} is not one of {LOOPS}")
+    try:
+        return reference.rule(traffic["metric"], rules)
+    except LookupError as e:
+        raise BadTraffic(str(e)) from None
+
+
+class Cell:
+    """One configuration under one traffic mix, on the program; the
+    metric's rule comes from ``rules`` (default bench/references/)."""
+
+    def __init__(self, config: dict, traffic: dict, graph: Graph,
+                 rules: Optional[Path] = None):
         import jax
 
         from repro.core import MatchConfig, MiningConfig, build_graph
 
-        if traffic["loop"] not in LOOPS:
-            raise ValueError(f"loop must be one of {LOOPS}")
+        self.rule = check(traffic, rules)
         self.config, self.traffic, self.graph = config, traffic, graph
         self.g = build_graph(graph.n, graph.edges, graph.labels,
                              n_labels=graph.n_labels,
@@ -64,8 +99,8 @@ class Cell:
             sigma=traffic["sigma"], lam=traffic["lam"],
             metric=traffic["metric"],
             max_pattern_size=config["max_pattern_size"],
-            execution=traffic["execution"],
-            match=MatchConfig.for_graph(self.g, cap=traffic["cap"]))
+            match=MatchConfig.for_graph(self.g, cap=traffic["cap"]),
+            **{k: traffic[k] for k in PASSED if k in traffic})
         self.spans = Spans()
         self._annotate = jax.profiler.TraceAnnotation
 
@@ -131,14 +166,16 @@ class Cell:
             [(p.adj, p.labels, s) for p, s in q.result.frequent])
             for q in queries]
 
-    def checker(self, width: Optional[int] = None) -> reference.Checker:
+    def checker(self, **control) -> reference.Checker:
+        """The reference under this cell's rule; ``control`` goes to the
+        rule's ``support`` (for mIS, ``width``)."""
         if not self.config["undirected"]:
             raise ValueError("the reference covers undirected graphs only")
         g = self.graph
         ref_g = reference.RefGraph(g.n, g.edges, g.labels, g.n_labels)
-        return reference.Checker(ref_g, self.traffic["sigma"],
+        return reference.Checker(ref_g, self.rule, self.traffic["sigma"],
                                  self.traffic["lam"],
-                                 self.config["max_pattern_size"], width=width)
+                                 self.config["max_pattern_size"], **control)
 
 
 def compare(checker: reference.Checker, answers: List[reference.Answer],

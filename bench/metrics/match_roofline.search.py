@@ -4,7 +4,7 @@ The least bytes the dispatched (candidate, root block) pairs need
 (`bench.roofline.BlockSums`) over the step programs' device time times the
 chip's peak HBM bandwidth (`bench/peaks.json`).  The step is bound by
 bytes: a 2-vertex match does no arithmetic to speak of."""
-from bench import reference, roofline, trace
+from bench import roofline, trace
 
 STEP = "jit_step"
 
@@ -27,8 +27,8 @@ def read(run):
         R = int(lvl.plan["root_block"])
         sums = roofline.BlockSums(g.n, g.edges, g.labels, g.n_labels, R)
         order = root_block_order(cell.g, R)
-        tau = reference.tau_threshold(cell.traffic["sigma"], cell.traffic["lam"], 2)
-        cands_eval = [p for p in cp if p.k * tau <= g.n]
+        tau = cell.rule.tau(cell.traffic["sigma"], cell.traffic["lam"], 2)
+        cands_eval = [p for p in cp if cell.rule.searchable(p.k, tau, g.n)]
         for (k, lo), grp in lvl.groups.items():
             for i, walked in enumerate(grp.blocks_run):
                 p = cands_eval[lo + i]

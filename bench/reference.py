@@ -3,13 +3,16 @@
 It imports nothing of the program.  From the same edge and label arrays it
 builds its own adjacency and recomputes, in straightforward numpy and
 Python, what a FLEXIS query answers: the candidate patterns of each level,
-and each candidate's mIS support.
+and each candidate's support.
 
-The mIS support is the size of a greedy maximal set of vertex-disjoint
-embeddings, taken in a fixed priority order and stopped once it reaches τ.
-A different order gives a different maximal set, and can change the
-frequent set.  So the order is part of the answer, and the reference takes
-it as the program documents it:
+What depends on the metric (the threshold τ, which candidates are searched,
+the support and how two supports compare) is one rule file per metric,
+``bench/references/<metric>.py``, found by the metric's name (`rule`).
+What every metric shares is here: the graph, the matching order, the block
+schedule and the candidates of each level.
+
+Supports that stop early depend on the order in which embeddings are met,
+so the reference takes that order as the program documents it:
 
 * root blocks of ``R`` vertex ids, walked in descending order of their
   largest out-degree (ties by block id);
@@ -25,13 +28,16 @@ it as the program documents it:
   ``position // C``.
 
 ``R`` and ``C`` follow from the graph alone (`geometry`).  The block
-schedule, the τ rule (FLEXIS Eq. 1) and the candidate rule are likewise
-written out here, not taken from the program.
+schedule and the candidate rule are likewise written out here, not taken
+from the program.
 """
 from __future__ import annotations
 
+import importlib.util
 import itertools
-import math
+import re
+from pathlib import Path
+from types import ModuleType
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -40,10 +46,23 @@ import numpy as np
 Pat = Tuple[np.ndarray, np.ndarray]
 
 
-def tau_threshold(sigma: int, lam: float, k: int) -> int:
-    """FLEXIS Eq. (1): τ = ⌊σ(1 − 1/k)λ + σ/k⌋, at least 1."""
-    k = max(k, 1)
-    return max(1, math.floor(sigma * (1.0 - 1.0 / k) * lam + sigma / k))
+RULES = Path(__file__).resolve().parent / "references"
+
+
+def rule(metric: str, directory: Optional[Path] = None) -> ModuleType:
+    """The rule file ``<directory>/<metric>.py`` (default:
+    bench/references/), loaded: its ``tau``, ``searchable``, ``support`` and
+    ``same`` (see ``bench/references/mis.py``).  LookupError where the
+    metric has none."""
+    path = (directory or RULES) / f"{metric}.py"
+    if not re.fullmatch(r"[A-Za-z0-9_]+", metric) or not path.is_file():
+        raise LookupError(f"metric {metric!r} has no rule file "
+                          f"{path.parent.name}/{metric}.py")
+    mod_spec = importlib.util.spec_from_file_location(
+        "bench_rule_" + metric, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
 
 
 def canonical_key(adj: np.ndarray, labels: np.ndarray) -> Tuple:
@@ -189,33 +208,6 @@ class RefGraph:
             rows = np.concatenate([rows[r[o]], cand[o][:, None]], axis=1)
         return rows
 
-    def support(self, pat: Pat, tau: int, *, width: Optional[int] = None
-                ) -> Tuple[int, int]:
-        """(greedy mIS support stopped at τ, root blocks walked).
-
-        ``width`` folds vertex ids into a used-vertex set of that many
-        slots: the control, which breaks disjointness on collisions.
-        """
-        plan = self.plan(*pat)
-        width = self.n if width is None else width
-        used = bytearray(width)
-        count = 0
-        walked = 0
-        for b in self.schedule:
-            walked += 1
-            rows = self.block_rows(pat, plan, int(b))
-            if rows.size:
-                for row in (rows % width).tolist():
-                    if count >= tau:
-                        break
-                    if not any(used[x] for x in row):
-                        for x in row:
-                            used[x] = 1
-                        count += 1
-            if count >= tau:
-                break
-        return count, walked
-
     # -- candidates ---------------------------------------------------------
     def level1(self) -> List[Pat]:
         """Every 2-vertex pattern with an embedding: a→b, and a⇄b where an
@@ -262,34 +254,42 @@ class RefGraph:
         return list(out.values())
 
 
+def _plain(value):
+    """A support as a Python number of its own type: an int stays an int,
+    a float a float."""
+    return value.item() if hasattr(value, "item") else value
+
+
 class Answer:
     """What one query reported: every searched pattern with its support."""
 
-    def __init__(self, searched: Iterable[Tuple[np.ndarray, np.ndarray, int]],
-                 frequent: Iterable[Tuple[np.ndarray, np.ndarray, int]]):
-        self.searched = [(np.asarray(a, bool), np.asarray(lab, np.int64), int(s))
+    def __init__(self, searched: Iterable[Tuple[np.ndarray, np.ndarray, float]],
+                 frequent: Iterable[Tuple[np.ndarray, np.ndarray, float]]):
+        self.searched = [(np.asarray(a, bool), np.asarray(lab, np.int64), _plain(s))
                          for a, lab, s in searched]
-        self.frequent = [(np.asarray(a, bool), np.asarray(lab, np.int64), int(s))
+        self.frequent = [(np.asarray(a, bool), np.asarray(lab, np.int64), _plain(s))
                          for a, lab, s in frequent]
 
 
 class Checker:
-    """Compares answers with the reference; caches per pattern."""
+    """Compares answers with the reference under one metric's ``rule``;
+    caches per pattern.  ``control`` goes to the rule's ``support``."""
 
-    def __init__(self, g: RefGraph, sigma: int, lam: float, max_k: int,
-                 width: Optional[int] = None):
-        self.g, self.sigma, self.lam, self.max_k = g, sigma, lam, max_k
-        self.width = width
-        self._support: Dict[Tuple, int] = {}
+    def __init__(self, g: RefGraph, rule: ModuleType, sigma: int, lam: float,
+                 max_k: int, **control):
+        self.g, self.rule = g, rule
+        self.sigma, self.lam, self.max_k = sigma, lam, max_k
+        self.control = control
+        self._support: Dict[Tuple, float] = {}
 
-    def tau(self, k: int) -> int:
-        return tau_threshold(self.sigma, self.lam, k)
+    def tau(self, k: int):
+        return self.rule.tau(self.sigma, self.lam, k)
 
-    def support(self, adj: np.ndarray, labels: np.ndarray) -> int:
+    def support(self, adj: np.ndarray, labels: np.ndarray):
         memo = (labels.tobytes(), adj.tobytes())
         if memo not in self._support:
-            self._support[memo] = self.g.support(
-                (adj, labels), self.tau(len(labels)), width=self.width)[0]
+            self._support[memo] = self.rule.support(
+                self.g, (adj, labels), self.tau(len(labels)), **self.control)[0]
         return self._support[memo]
 
     def compare(self, ans: Answer, *, whole: bool) -> Dict[str, int]:
@@ -310,7 +310,7 @@ class Checker:
         ref_frequent = set()
         for key, (adj, lab, s) in got.items():
             ref = self.support(adj, lab)
-            wrong_supports += ref != s
+            wrong_supports += not self.rule.same(s, ref)
             if ref >= self.tau(len(lab)):
                 ref_frequent.add(key)
         got_frequent = {canonical_key(a, lab) for a, lab, _ in ans.frequent}
@@ -324,7 +324,7 @@ class Checker:
             k, expected = 2, set()
             while want:
                 want = {key: p for key, p in want.items()
-                        if k * self.tau(k) <= g.n}
+                        if self.rule.searchable(k, self.tau(k), g.n)}
                 expected |= set(want)
                 # the program's own drawing of a pattern where it has one
                 frequent_k = {key for key, (a, lab) in want.items()

@@ -4,16 +4,19 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name from ``BENCHMARK.json``: the cell's
-configuration file, its traffic file ``bench/traffic/<mix>.json`` and one
-reader ``bench/metrics/<metric>.py`` per metric.  The run makes the graph
-from ``--seed``, warms up (``setup_s``), drives the program for
+configuration file, its traffic file ``bench/traffic/<mix>.json``, the
+rule file ``bench/references/<metric>.py`` of the traffic's metric and one
+reader ``bench/metrics/<metric>.py`` per reported metric.  The run makes
+the graph from ``--seed``, warms up (``setup_s``), drives the program for
 ``--seconds``, checks every answer the window produced against the plain
-reference (``bench/reference.py``), and prints one JSON line last.  With
-``--trace 1`` the window runs under the profiler and the line carries the
-per-layer metrics instead of the end-to-end ones.
+reference (``bench/reference.py`` under the metric's rule), and prints one
+JSON line last.  With ``--trace 1`` the window runs under the profiler and
+the line carries the per-layer metrics instead of the end-to-end ones.
 
 It refuses to report from anything but a TPU: without one, or with fewer
-chips than the cell asks for, it exits non-zero and prints no result.
+chips than the cell asks for, it exits non-zero and prints no result.  So
+it does, before set-up, for a traffic file the harness cannot check: a
+metric with no rule file, or a key the driver neither reads nor passes on.
 """
 from __future__ import annotations
 
@@ -31,6 +34,8 @@ from typing import Dict, List, Optional
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 sys.path.insert(0, str(ROOT / "src"))
+
+from bench import drive  # noqa: E402  (after the paths above)
 
 # Each number compared with the reference, and its limit: the answers are
 # exact, so any wrong one fails.
@@ -96,21 +101,24 @@ def devices_for(jax, chips: int):
 
 def run(workload: str, seed: int, seconds: float, traced: bool, *,
         check_device: bool = True, bench: Optional[dict] = None,
-        files=None, t_start: Optional[float] = None) -> dict:
+        files=None, rules: Optional[Path] = None,
+        t_start: Optional[float] = None) -> dict:
     """One run of ``workload``; returns the result line as a dict.
 
-    ``bench`` and ``files`` stand in for BENCHMARK.json and the cell's files
-    (tests use them to run a cell at a size a CPU can hold).
+    ``bench``, ``files`` and ``rules`` stand in for BENCHMARK.json, the
+    cell's files and bench/references/ (tests use them to run a cell at a
+    size a CPU can hold, or under another rule).
     """
     t_start = time.perf_counter() if t_start is None else t_start
     bench = spec() if bench is None else bench
     cell, config, traffic = cell_files(bench, workload) if files is None else files
+    drive.check(traffic, rules)
     metrics = cell_metrics(bench, workload, traced)
     readers = {m["name"]: reader(m["name"]) for m in metrics}
 
     import jax
 
-    from bench import drive, jaxmon, rmat, trace
+    from bench import jaxmon, rmat, trace
 
     devices = (devices_for(jax, cell["chips"]) if check_device
                else jax.devices()[:cell["chips"]])
@@ -118,7 +126,7 @@ def run(workload: str, seed: int, seconds: float, traced: bool, *,
     counter = jaxmon.CompileCounter(jax)
 
     graph = rmat.config_graph(config, seed)
-    c = drive.Cell(config, traffic, graph)
+    c = drive.Cell(config, traffic, graph, rules)
     c.warm_up()
     setup_s = time.perf_counter() - t_start
     snap = counter.snapshot()
@@ -209,7 +217,7 @@ def main(argv=None) -> int:
     try:
         line = run(args.workload, args.seed, args.seconds, bool(args.trace),
                    t_start=t_start)
-    except NoChip as e:
+    except (NoChip, drive.BadTraffic) as e:
         say(f"no result: {e}")
         return 3
     print(json.dumps(line))

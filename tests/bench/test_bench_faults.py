@@ -4,14 +4,16 @@ These drive the whole of a run on the CPU (only the look for a chip is
 skipped), at a size a test run can hold, with the program's batched block
 step broken in each of the ways a cell can show: a step that returns its
 state unchanged, half of the batch left out, and a support altered where
-it is produced.  One chip has no exchange between chips to leave out."""
+it is produced.  One chip has no exchange between chips to leave out.
+A sound step under a rule file whose support is off by one is not correct
+either: the metric's rule file decides."""
 import jax
 import jax.numpy as jnp
 import pytest
 
 from bench import run as bench_run
 
-from test_bench_reference import GNUTELLA, MICO, QUERY
+from test_bench_reference import GNUTELLA, MICO, QUERY, RULES
 
 CELLS = {
     "gnutella.q3-mis": (GNUTELLA, dict(QUERY, execution="auto")),
@@ -48,7 +50,7 @@ def _broken(orig, fault):
     return step_fn
 
 
-def _run(cell_name, monkeypatch, fault=None):
+def _run(cell_name, monkeypatch, fault=None, rules=None):
     import repro.core.batched as batched
     import repro.core.planner as planner
 
@@ -60,7 +62,7 @@ def _run(cell_name, monkeypatch, fault=None):
     cell = next(w for w in spec["workloads"] if w["name"] == cell_name)
     config, traffic = CELLS[cell_name]
     return bench_run.run(cell_name, 2**31 + 11, 4.0, False, check_device=False,
-                         bench=spec, files=(cell, config, traffic))
+                         bench=spec, files=(cell, config, traffic), rules=rules)
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
@@ -72,3 +74,11 @@ def test_a_broken_step_is_not_correct(cell, fault, monkeypatch):
     assert list(line)[-1] == "checks"
     assert set(line["metrics"]) == {m["name"] for m in bench_run.cell_metrics(
         bench_run.spec(), cell, traced=False)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_metrics_rule_file_decides_correct(cell, monkeypatch, tmp_path):
+    (tmp_path / "mis.py").write_text(RULES["off_by_one"])
+    line = _run(cell, monkeypatch, rules=tmp_path)
+    assert line["correct"] is False
+    assert line["checks"]["wrong_supports"]["value"] > 0

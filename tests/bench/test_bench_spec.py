@@ -8,8 +8,8 @@ from types import SimpleNamespace
 
 import pytest
 
+from bench import drive, roofline
 from bench import run as bench_run
-from bench import roofline
 
 ROOT = bench_run.ROOT
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -43,6 +43,7 @@ def test_spec_keys_and_names():
 def test_every_cell_finds_its_files_and_reports_enough(cell):
     _, config, traffic = bench_run.cell_files(SPEC, cell)
     assert traffic["loop"] in ("cut", "closed")
+    assert callable(drive.check(traffic).support)
     ends = bench_run.cell_metrics(SPEC, cell, traced=False)
     layers = bench_run.cell_metrics(SPEC, cell, traced=True)
     names = {m["name"] for m in ends}
@@ -75,15 +76,24 @@ def test_peaks_refuse_an_unknown_device_kind():
         roofline.peaks("cpu")
 
 
-def _command(cwd, env_extra=None):
+def _command(cwd, env_extra=None, workload="mico.l1-mis"):
     import os
 
     env = dict(os.environ, JAX_PLATFORMS="cpu", **(env_extra or {}))
     return subprocess.run(
         [sys.executable, str(cwd / SPEC["command"][1]),
-                               "--workload", "mico.l1-mis", "--seed",
+                               "--workload", workload, "--seed",
                                str(2**31 + 7), "--seconds", "1", "--trace", "0"],
         cwd=cwd, env=env, capture_output=True, text=True, timeout=300)
+
+
+def _copy_benchmark(to):
+    import shutil
+
+    shutil.copy(ROOT / "BENCHMARK.json", to)
+    for p in SPEC["paths"]:
+        shutil.copytree(ROOT / p, to / p,
+                        ignore=shutil.ignore_patterns("__pycache__"))
 
 
 def test_the_command_refuses_a_cpu_and_prints_no_result():
@@ -94,12 +104,32 @@ def test_the_command_refuses_a_cpu_and_prints_no_result():
 
 
 def test_the_benchmark_alone_does_not_run(tmp_path):
-    import shutil
-
-    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
-    for p in SPEC["paths"]:
-        shutil.copytree(ROOT / p, tmp_path / p,
-                        ignore=shutil.ignore_patterns("__pycache__"))
+    _copy_benchmark(tmp_path)
     out = _command(tmp_path)
     assert out.returncode != 0
     assert out.stdout.strip() == ""
+
+
+@pytest.mark.parametrize("change,name", [
+    ({"metric": "mni"}, "mni"),
+    ({"complete": True}, "complete"),
+    ({"escalate": False}, "escalate"),
+    ({"root_order": "vertex"}, "root_order"),
+    ({"generation": "extend"}, "generation"),
+])
+def test_the_command_refuses_a_traffic_it_cannot_check(change, name, tmp_path):
+    """Before set-up, with the name in the message: a metric with no rule
+    file, or a key the driver neither reads nor passes to the program."""
+    _copy_benchmark(tmp_path)
+    _, _, traffic = bench_run.cell_files(SPEC, "gnutella.q3-mis")
+    (tmp_path / "bench" / "traffic" / "refused.json").write_text(
+        json.dumps(dict(traffic, **change)))
+    spec = dict(SPEC, workloads=SPEC["workloads"] + [
+        {"name": "gnutella.refused", "config": "gnutella", "traffic": "refused",
+         "chips": 1, "why": "a traffic the harness cannot check"}])
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    out = _command(tmp_path, {"PYTHONPATH": str(ROOT / "src")}, "gnutella.refused")
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""
+    assert "no result" in out.stderr and name in out.stderr
+    assert "setup:" not in out.stderr
