@@ -6,10 +6,13 @@ program's answers on the CPU, at sizes a test run can hold, and the control
 What depends on the metric comes from its rule file alone, and the traffic
 file reaches the program only through the keys the driver passes on."""
 import re
+import shutil
+from pathlib import Path
 
 import pytest
 
 from bench import drive, reference, rmat
+from repro.core.flexis import _METRICS
 
 GNUTELLA = {"name": "gnutella", "vertices": 315, "edges": 1038, "labels": 5,
             "undirected": True, "max_pattern_size": 3,
@@ -127,7 +130,7 @@ def test_an_execution_option_reaches_the_program(key, value):
 
 
 @pytest.mark.parametrize("traffic,name", [
-    (dict(QUERY, metric="mni"), "mni"),
+    (dict(QUERY, metric="nmi"), "nmi"),
     (dict(QUERY, metric="../references/mis"), "../references/mis"),
     (dict(QUERY, complete=True), "complete"),
     (dict(QUERY, sigam=25), "sigam"),
@@ -136,6 +139,53 @@ def test_an_execution_option_reaches_the_program(key, value):
 def test_a_traffic_the_reference_cannot_check_is_refused(traffic, name):
     with pytest.raises(drive.BadTraffic, match=re.escape(name)):
         drive.check(traffic)
+
+
+def _refused(metric):
+    return pytest.raises(drive.BadTraffic, match=re.escape(repr(metric)))
+
+
+@pytest.mark.parametrize("metric", _METRICS)
+def test_check_gives_the_rule_file_of_a_metric_or_refuses_it(metric):
+    """Whether a metric is checked follows bench/references/ alone."""
+    path = reference.RULES / f"{metric}.py"
+    traffic = dict(QUERY, metric=metric)
+    if path.is_file():
+        assert Path(drive.check(traffic).__file__) == path
+    else:
+        with _refused(metric):
+            drive.check(traffic)
+
+
+@pytest.mark.parametrize("metric", _METRICS)
+def test_check_refuses_a_metric_whose_rule_its_directory_lacks(metric, tmp_path):
+    others = [p for p in reference.RULES.glob("*.py") if p.stem != metric]
+    for p in others:
+        shutil.copy(p, tmp_path)
+    with _refused(metric):
+        drive.check(dict(QUERY, metric=metric), tmp_path)
+    for p in others:
+        rule = drive.check(dict(QUERY, metric=p.stem), tmp_path)
+        assert Path(rule.__file__) == tmp_path / p.name
+
+
+@pytest.mark.parametrize("path", sorted(reference.RULES.glob("[!_]*.py")),
+                         ids=lambda p: p.stem)
+def test_every_rule_file_keeps_the_contract(path):
+    """What `reference.Checker` calls, on each rule file present: a later
+    rule file is checked here as it is added."""
+    rule = reference.rule(path.stem)
+    data = rmat.config_graph(MICO, 2**31 + 23)
+    g = reference.RefGraph(data.n, data.edges, data.labels, data.n_labels)
+    for adj, lab in g.level1()[:3]:
+        tau = rule.tau(25, 0.4, len(lab))
+        assert isinstance(rule.searchable(len(lab), tau, g.n), bool)
+        got = rule.support(g, (adj, lab), tau)
+        assert isinstance(got, tuple) and len(got) == 2
+        value, walked = got
+        assert isinstance(value, (int, float)) and value >= 0
+        assert isinstance(walked, int) and 1 <= walked <= len(g.schedule)
+        assert rule.same(value, value)
 
 
 def test_answers_keep_the_type_of_each_support():

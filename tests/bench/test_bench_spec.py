@@ -111,7 +111,7 @@ def test_the_benchmark_alone_does_not_run(tmp_path):
 
 
 @pytest.mark.parametrize("change,name", [
-    ({"metric": "mni"}, "mni"),
+    ({"metric": "nmi"}, "nmi"),
     ({"complete": True}, "complete"),
     ({"escalate": False}, "escalate"),
     ({"root_order": "vertex"}, "root_order"),
@@ -132,4 +132,18 @@ def test_the_command_refuses_a_traffic_it_cannot_check(change, name, tmp_path):
     assert out.returncode != 0
     assert out.stdout.strip() == ""
     assert "no result" in out.stderr and name in out.stderr
+    assert "setup:" not in out.stderr
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
+def test_the_command_refuses_a_cell_whose_rule_file_is_gone(cell, tmp_path):
+    """The same refusal, for each cell as it stands, with its metric's rule
+    file taken out of the copy."""
+    _copy_benchmark(tmp_path)
+    _, _, traffic = bench_run.cell_files(SPEC, cell)
+    (tmp_path / "bench" / "references" / f"{traffic['metric']}.py").unlink()
+    out = _command(tmp_path, {"PYTHONPATH": str(ROOT / "src")}, cell)
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""
+    assert "no result" in out.stderr and repr(traffic["metric"]) in out.stderr
     assert "setup:" not in out.stderr
